@@ -388,6 +388,9 @@ def _spawn_nodes(n: int, backend: str, shared: Path, root: Path,
     script = root / "cluster_node.py"
     script.write_text(_CLUSTER_NODE)
     procs = []
+    # the nodes stay on the CPU on purpose: this scenario counts
+    # translations and AOT restores across processes (a chip belongs to
+    # one process), so its timings are CPU timings, not chip numbers
     for i in range(n):
         node_dir = root / f"{tag}-node{i}"
         procs.append(subprocess.Popen(

@@ -32,6 +32,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                              "slow, so opt-in)")
     opts = parser.parse_args(argv)
 
+    from repro.device import configure_compile_cache
+    configure_compile_cache()
+
     from benchmarks import (bench_fleet, bench_kernels, bench_migration,
                             bench_overhead, bench_portability,
                             bench_serving, bench_streams,

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core import HetSession, migrate
 from repro.core import kernels_suite as suite
+from repro.device import configure_compile_cache
 
 
 def main() -> int:
@@ -98,4 +99,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     sys.exit(main())

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.core.fleet import MID_KERNEL, FleetCoordinator
 from repro.core.kernels_suite import example_launch
+from repro.device import configure_compile_cache
 
 
 def main():
@@ -93,4 +94,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

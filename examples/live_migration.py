@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import Engine, Snapshot, get_backend
 from repro.core import kernels_suite as suite
+from repro.device import configure_compile_cache
 
 
 def kernel_migration():
@@ -69,5 +70,6 @@ def job_migration():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     kernel_migration()
     job_migration()

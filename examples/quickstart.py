@@ -6,6 +6,7 @@ import numpy as np
 
 from repro.core import Engine, HetSession, get_backend, migrate
 from repro.core import kernels_suite as suite
+from repro.device import configure_compile_cache
 
 
 def main():
@@ -52,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

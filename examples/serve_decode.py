@@ -23,6 +23,7 @@ import argparse
 import time
 
 import numpy as np
+from repro.device import configure_compile_cache
 
 
 def het_tier(requests_per_tenant: int = 24) -> None:
@@ -275,4 +276,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

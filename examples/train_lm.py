@@ -17,6 +17,7 @@ import jax
 from repro import configs
 from repro.configs.base import ParallelCfg, ShapeCfg
 from repro.runtime.train_loop import Trainer
+from repro.device import configure_compile_cache
 
 
 def main():
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -174,7 +174,10 @@ def export_translation(
                          for s in flat])
     fn, exe = jitted, None
     try:
-        compiled = jitted.lower(*structs).compile()
+        # compile the exported module itself: re-lowering ``jitted`` would
+        # trace the Python body a second time, which costs as much as the
+        # export did (seconds per segment kernel at decode width)
+        compiled = jax.jit(exported.call).lower(*structs).compile()
         t2 = time.perf_counter()
         fn = compiled
         try:

@@ -1,92 +1,315 @@
 """Pallas backend — hetIR segments lowered to TPU kernels.
 
 This is the "SIMT hardware" target: one ``pl.pallas_call`` per segment
-(the paper: *"each segment is a separate kernel"*), with
+(the paper: *"each segment is a separate kernel"*).  One emitter serves
+two plans:
 
-* grid ``(num_blocks,)`` — one grid step per hetIR thread block;
-* per-thread registers as ``[num_blocks, block_size]`` arrays, BlockSpec'd
-  ``(1, block_size)`` so each grid step sees its own block's register file in
-  VMEM — the register-file-in-memory handoff the paper uses between segment
-  kernels;
-* hetIR shared memory as a ``(1, shared_size)`` VMEM-resident block;
-* global buffers staged into VMEM.  Buffers whose every access is *coalesced*
-  (indexed exactly by ``GET_GLOBAL_ID``) are tiled ``(block_size,)`` per grid
-  step — the fast path; all other buffers are staged whole per grid step (the
-  gather/DMA path, mirroring the paper's Tenstorrent fallback).  Written
-  non-coalesced buffers use the revisited-output accumulator pattern: the
-  output block is initialized from the input at grid step 0 and all later
-  reads/writes go through the output ref (constant ``index_map`` keeps the
-  block resident in VMEM across the sequential TPU grid).
+* **scalar-per-thread**: grid ``(num_blocks,)``, one grid step per hetIR
+  thread block.  Registers travel as ``[num_blocks, 1, block_size]``
+  arrays BlockSpec'd ``(1, block_size)`` per step — the
+  register-file-in-memory handoff the paper uses between segment kernels —
+  and hetIR shared memory as the block's ``(rows, 128)`` VMEM slab.
+* **block-tiled** (when :func:`~repro.core.passes.block_lower` proves a
+  segment *lane-independent*): the grid tiles the flat element domain
+  ``N = num_blocks * block_size`` into constexpr ``BLOCK`` chunks — the
+  Triton vector-addition idiom.  Registers travel as ``[1, N]`` arrays
+  BlockSpec'd ``(1, BLOCK)``; buffers whose every access is exactly the
+  flat global id are tiled the same way and read or written as whole
+  tiles.  ``PallasBackend.block_stats`` counts both plans and the proof's
+  refusal reasons.  ``HETGPU_BLOCK_LOWER=0`` disables this plan;
+  ``HETGPU_BLOCK_MAX`` caps the tile size (default 1024).
 
-**Block-tiled fast path** (the scalar-per-thread speed-ceiling fix): when
-:func:`~repro.core.passes.block_lower` proves a segment *lane-independent*,
-the segment is emitted as one ``pl.pallas_call`` whose grid tiles the flat
-*element* domain ``N = num_blocks * block_size`` into constexpr ``BLOCK``
-chunks — the Triton vector-addition idiom — instead of one grid step per
-hetIR block.  Registers travel as ``[1, N]`` flat arrays BlockSpec'd
-``(1, BLOCK)``; buffers whose every access is exactly the flat global id
-are BlockSpec-tiled ``(BLOCK,)`` and everything else takes the staged
-gather path.  Segments the proof rejects (shared memory, collectives,
-atomics, unprovable store indices) fall back to the scalar-per-thread path
-below; ``PallasBackend.block_stats`` counts both and records the refusal
-reasons.  ``HETGPU_BLOCK_LOWER=0`` disables the fast path;
-``HETGPU_BLOCK_MAX`` caps the tile size (default 1024).
+Every other global buffer enters as a window of a 3-D view chosen by the
+index-range analysis of :mod:`.pallas_mem`: a K/V tile addressed by
+``(head, tile)`` moves into VMEM alone, and only buffers with no provable
+window are staged whole.  Barrier-loop counters reach the kernel as
+scalar-prefetch operands, so window index maps can follow them.
 
-On this CPU container kernels execute with ``interpret=True``; the emitted
-BlockSpecs are the TPU contract.  Lane width: ``block_size`` should be a
-multiple of 128 for peak TPU efficiency (any size is functionally correct).
+Kernels compile through Mosaic on a TPU and are interpreted on the CPU
+backend (how the tests run); the emitted kernel body is the same in both.
+On the chip a segment the emitter cannot lower raises
+:class:`~.pallas_mem.PallasRefusal` at translate time — never an
+interpreted kernel.
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ...device import pallas_interpret
 from .. import hetir as ir
 from ..cache import TranslationCache
-from ..passes import (BlockPlan, block_lower, choose_block,
-                      refusal_category)
-from ..segments import SegNode
+from ..passes import block_lower, choose_block, refusal_category
+from ..segments import LoopStart, SegNode, segment_program
 from .base import (Backend, HostState, Launch, export_translation,
                    scalar_signature, state_signature)
+from .pallas_mem import (LANES, VMEM_BUDGET, BufLayout, PallasRefusal,
+                         RefMemory, entry_forms, pad_len, plan_buffers,
+                         vmem_bytes)
 from .semantics import Env, eval_stmts
 
 
-def _coalesced_buffers(seg: SegNode) -> set:
-    """Buffers where every LD/ST index is exactly a GET_GLOBAL_ID register."""
-    gid_regs: set = set()
-    access: Dict[str, List[str]] = {}
+def _kdt(dt) -> np.dtype:
+    """Kernel-side dtype: Mosaic has no boolean memrefs."""
+    dt = np.dtype(dt)
+    return np.dtype(np.int32) if dt == np.bool_ else dt
 
-    def walk(stmts):
-        for s in stmts:
-            if isinstance(s, ir.Op):
-                if s.opcode == ir.GET_GLOBAL_ID:
-                    gid_regs.add(s.dest.name)
-                elif s.opcode in (ir.LD_GLOBAL, ir.ST_GLOBAL, ir.ATOMIC_ADD):
-                    idx = s.args[1]
-                    access.setdefault(s.args[0], []).append(
-                        idx.name if isinstance(idx, ir.Reg) else "#imm")
-            elif isinstance(s, (ir.Pred, ir.Loop)):
-                walk(s.body)
 
-    walk(seg.stmts)
-    return {buf for buf, idxs in access.items()
-            if all(i in gid_regs for i in idxs)}
+def _program_nodes(prog: ir.Program):
+    nodes = getattr(prog, "_nodes_cache", None)
+    return nodes if nodes is not None else segment_program(prog)
+
+
+def _segment_entry(launch: Launch, seg: SegNode):
+    """Index-range forms of the registers at ``seg``'s entry."""
+    prog = launch.program
+    segs = [n for n in _program_nodes(prog) if isinstance(n, SegNode)]
+    entries = entry_forms(prog, launch.num_blocks, launch.block_size,
+                          launch.scalars)
+    pos = [i for i, n in enumerate(segs) if n.index == seg.index]
+    if len(entries) == len(segs) and pos:
+        return entries[pos[0]]
+    return {r.name: None for r in ir.body_defs_uses(prog.body)[0]}
+
+
+def emit_segment(seg: SegNode, launch: Launch, reg_sig: Tuple,
+                 glb_sig: Tuple, shared_sig, block_cand: Optional[int],
+                 interpret: bool):
+    """Emit the segment's ``pl.pallas_call`` wrapped in its layout
+    conversion.  Returns ``(fn, meta, example)``: ``fn(loop_counters,
+    *regs, [shared], *globals)`` over host-state shaped arrays, the
+    run-time I/O plan, and ShapeDtypeStructs of ``fn``'s arguments.
+
+    Tries the block-tiled plan first (``block_cand`` is the candidate
+    tile size, None when disabled); the scalar-per-thread plan is the
+    fallback, with the refusal reason in ``meta["block_reason"]``."""
+    B, T = launch.num_blocks, launch.block_size
+    N = B * T
+    plan, block_reason = None, "disabled"
+    if block_cand is not None:
+        plan, block_reason = block_lower(
+            seg.stmts, B, T, block_cand,
+            buffer_lens={n: shape[0] for n, shape, _ in glb_sig
+                         if len(shape) == 1})
+        if plan is not None and plan.block % LANES and plan.block != N:
+            # a (1, BLOCK) tile must be lane-aligned or the whole domain
+            plan, block_reason = None, "bad-block:lanes"
+    flat = plan is not None
+    stmts = plan.stmts if flat else seg.stmts
+    lanes = plan.block if flat else T
+    grid = plan.grid if flat else B
+    lane_shape = (1, lanes)
+
+    reg_dt = {n: np.dtype(dt) for n, _, dt in reg_sig}
+    seg_defs = {r.name for r in seg.defs}
+    loop_vars = tuple(sorted(
+        n.var.name for n in _program_nodes(launch.program)
+        if isinstance(n, LoopStart) and n.var.name in reg_dt
+        and n.var.name not in seg_defs))
+    reg_io = tuple(n for n, _, _ in reg_sig if n not in loop_vars)
+    new_regs = tuple(sorted(r.name for r in seg.defs if r.name not in reg_dt))
+    new_dt = {r.name: ir.np_dtype(r.dtype) for r in seg.defs
+              if r.name in new_regs}
+    out_dt = {**{n: reg_dt[n] for n in reg_io}, **new_dt}
+    # buffers the segment never touches bypass the kernel
+    touched = seg.greads | seg.gwrites
+    glb_sig = tuple(e for e in glb_sig if e[0] in touched)
+    glb_names = tuple(n for n, _, _ in glb_sig)
+    glb_len = {n: shape[0] for n, shape, _ in glb_sig}
+    glb_dt = {n: np.dtype(dt) for n, _, dt in glb_sig}
+    written = tuple(sorted(seg.gwrites))
+    has_shared = shared_sig is not None and not flat
+
+    layouts = plan_buffers(stmts, _segment_entry(launch, seg), glb_len, B,
+                           T, launch.scalars,
+                           tiled=plan.tiled if flat else frozenset(),
+                           flat_block=plan.block if flat else None)
+    syms = {"g"} | {"loop:" + v for v in loop_vars}
+    for n, lay in layouts.items():
+        if any(s not in syms for s, _ in lay.terms):   # stage it whole
+            layouts[n] = BufLayout(lay.length, lay.padded, lay.padded)
+    if not interpret:
+        need = vmem_bytes(layouts, {n: _kdt(glb_dt[n]) for n in glb_names},
+                          written)
+        if need > VMEM_BUDGET:
+            raise PallasRefusal(f"vmem-budget:{seg.label}:{need}B")
+
+    if flat:
+        def reg_spec():
+            return pl.BlockSpec((1, lanes), lambda g, s: (0, g))
+    else:
+        def reg_spec():
+            return pl.BlockSpec((None, 1, T), lambda g, s: (g, 0, 0))
+
+    def glb_spec(n):
+        lay = layouts[n]
+        if lay.tiled:
+            return pl.BlockSpec((1, lanes), lambda g, s: (0, g))
+
+        def index_map(g, s, lay=lay):
+            loops = {v: s[j] for j, v in enumerate(loop_vars)}
+            return lay.index(g, loops), 0, 0
+        return pl.BlockSpec((None, lay.window // LANES, LANES), index_map)
+
+    def glb_shape(n):
+        lay = layouts[n]
+        shape = (1, lay.length) if lay.tiled else \
+            (lay.padded // lay.window, lay.window // LANES, LANES)
+        return jax.ShapeDtypeStruct(shape, _kdt(glb_dt[n]))
+
+    reg_shape = (1, N) if flat else (B, 1, T)
+    if has_shared:
+        S = shared_sig[0][1]
+        Sp = pad_len(S)
+        sh_dt = _kdt(shared_sig[1])
+        sh_spec = pl.BlockSpec((None, Sp // LANES, LANES),
+                               lambda g, s: (g, 0, 0))
+    in_specs = [reg_spec() for _ in reg_io]
+    in_specs += [sh_spec] if has_shared else []
+    in_specs += [glb_spec(n) for n in glb_names]
+    out_names = reg_io + new_regs
+    out_specs = [reg_spec() for _ in out_names]
+    out_shapes = [jax.ShapeDtypeStruct(reg_shape, _kdt(out_dt[n]))
+                  for n in out_names]
+    if has_shared:
+        out_specs.append(sh_spec)
+        out_shapes.append(jax.ShapeDtypeStruct((B, Sp // LANES, LANES),
+                                               sh_dt))
+    out_specs += [glb_spec(n) for n in written]
+    out_shapes += [glb_shape(n) for n in written]
+    n_in = len(in_specs)
+    first_glb_in = 1 + len(reg_io) + int(has_shared)
+    first_glb_out = len(out_names) + int(has_shared)
+    aliases = {first_glb_in + glb_names.index(n): first_glb_out + k
+               for k, n in enumerate(written)}
+    scalars = dict(launch.scalars)
+
+    def kernel(s_ref, *refs):
+        ins, outs = iter(refs[:n_in]), iter(refs[n_in:])
+        g = pl.program_id(0)
+        loops = {v: s_ref[j] for j, v in enumerate(loop_vars)}
+        reg_in = {n: next(ins) for n in reg_io}
+        sh_in = next(ins) if has_shared else None
+        glb_in = {n: next(ins) for n in glb_names}
+        reg_out = {n: next(outs) for n in out_names}
+        sh_out = next(outs) if has_shared else None
+        glb_out = {n: next(outs) for n in written}
+
+        # written buffers are updated in place in the output window,
+        # seeded from the input window: every step when the window moves
+        # with the step, once when it stays resident across steps
+        for n in written:
+            lay = layouts[n]
+            if lay.tiled or lay.per_step:
+                glb_out[n][...] = glb_in[n][...]
+            else:
+                @pl.when(g == 0)
+                def _init(n=n):
+                    glb_out[n][...] = glb_in[n][...]
+        if has_shared:
+            sh_out[...] = sh_in[...]
+        bufs = {}
+        for n in glb_names:
+            lay = layouts[n]
+            ref = glb_out[n] if n in written else glb_in[n]
+            base = 0 if lay.tiled else lay.index(g, loops) * lay.window
+            bufs[n] = (ref, base, lay)
+
+        regs = {}
+        for n in reg_io:
+            v = reg_in[n][...]
+            regs[n] = v != 0 if reg_dt[n] == np.bool_ else v
+        for v in loop_vars:
+            regs[v] = jnp.full(lane_shape, loops[v], reg_dt[v])
+        env = Env(regs=regs, shared=None, globals_={}, scalars=scalars,
+                  num_blocks=B, block_size=T,
+                  block_offset=0 if flat else g)
+        env.lane_shape = lane_shape
+        if flat:
+            env.flat_base = g * lanes
+        env.mem = RefMemory(bufs, sh_out, lane_shape)
+        env.div_barrier = env.ieee_div = interpret
+        eval_stmts(stmts, env, mask=None)
+
+        for n, ref in reg_out.items():
+            if n in env.regs:
+                v = jnp.broadcast_to(env.regs[n], lane_shape)
+            elif n in reg_in:  # untouched register: pass through
+                v = reg_in[n][...]
+            else:  # defined only in a zero-trip loop: zeros
+                v = jnp.zeros(lane_shape, ref.dtype)
+            ref[...] = v.astype(ref.dtype)
+
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(grid,), in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shapes,
+        input_output_aliases=aliases,
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )
+
+    def fn(loop_counters, *args):
+        args = list(args)
+        kin = []
+        for n in reg_io:
+            kin.append(args.pop(0).astype(_kdt(reg_dt[n])).reshape(reg_shape))
+        if has_shared:
+            sh = args.pop(0).astype(sh_dt)
+            sh = jnp.pad(sh, ((0, 0), (0, Sp - S)))
+            kin.append(sh.reshape(B, Sp // LANES, LANES))
+        for n in glb_names:
+            lay = layouts[n]
+            a = args.pop(0).astype(_kdt(glb_dt[n]))
+            if not lay.tiled:
+                a = jnp.pad(a, (0, lay.padded - lay.length))
+            kin.append(a.reshape(glb_shape(n).shape))
+        outs = list(call(loop_counters, *kin))
+        res = [outs.pop(0).reshape(B, T).astype(out_dt[n])
+               for n in out_names]
+        if has_shared:
+            res.append(outs.pop(0).reshape(B, Sp)[:, :S]
+                       .astype(shared_sig[1]))
+        for n in written:
+            res.append(outs.pop(0).reshape(-1)[:glb_len[n]]
+                       .astype(glb_dt[n]))
+        return tuple(res)
+
+    meta = dict(reg_io=reg_io, new_regs=new_regs, loop_vars=loop_vars,
+                glb_names=glb_names, written=written, has_shared=has_shared,
+                block=None if not flat else dict(
+                    block=plan.block, grid=plan.grid,
+                    tiled=tuple(sorted(plan.tiled))),
+                block_reason=None if flat else block_reason,
+                windows={n: lay.window for n, lay in layouts.items()
+                         if not lay.tiled and lay.window < lay.padded})
+    example = tuple(
+        [jax.ShapeDtypeStruct((max(1, len(loop_vars)),), np.int32)]
+        + [jax.ShapeDtypeStruct(shape, np.dtype(dt))
+           for n, shape, dt in reg_sig if n not in loop_vars]
+        + ([jax.ShapeDtypeStruct(shared_sig[0], np.dtype(shared_sig[1]))]
+           if has_shared else [])
+        + [jax.ShapeDtypeStruct(shape, np.dtype(dt))
+           for _, shape, dt in glb_sig])
+    return fn, meta, example
 
 
 class PallasBackend(Backend):
     name = "pallas"
 
-    def __init__(self, interpret: bool = True,
-                 cache: "TranslationCache" = None):
+    def __init__(self, cache: "TranslationCache" = None):
         super().__init__(cache)
-        self.interpret = interpret
         # fast-path observability: how many segment executions took the
-        # block-tiled vs scalar-per-thread path, and why refusals happened
+        # block-tiled vs scalar-per-thread plan, and why refusals happened
         self.block_stats: Dict[str, object] = \
             {"tiled": 0, "scalar": 0, "reasons": {}}
 
@@ -119,327 +342,53 @@ class PallasBackend(Backend):
 
     def _build(self, seg: SegNode, launch: Launch, reg_sig: Tuple,
                glb_sig: Tuple, shared_sig, block_cand: Optional[int] = None):
-        """Emit, trace, and export the segment's ``pl.pallas_call`` kernel.
-        Returns ``((jitted fn, meta), persist)`` for the translation cache;
-        the persisted payload is the serialized ``jax.export`` artifact plus
-        ``meta``, so a warm process skips re-emitting and re-tracing.
-
-        Tries the block-tiled fast path first (``block_cand`` is the
-        candidate tile size, None when disabled); the scalar-per-thread
-        lowering below is the fallback, with the refusal reason recorded in
-        ``meta["block_reason"]``."""
-        B, T = launch.num_blocks, launch.block_size
-        block_reason = "disabled"
-        if block_cand is not None:
-            plan, block_reason = block_lower(
-                seg.stmts, B, T, block_cand,
-                buffer_lens={n: shape[0] for n, shape, _ in glb_sig
-                             if len(shape) == 1})
-            if plan is not None:
-                return self._build_block(plan, seg, launch, reg_sig, glb_sig)
-        scalars = dict(launch.scalars)
-        reg_names = tuple(n for n, _, _ in reg_sig)
-        reg_dtypes = {n: dt for n, _, dt in reg_sig}
-        glb_names = tuple(n for n, _, _ in glb_sig)
-        glb_shapes = {n: (shape, dt) for n, shape, dt in glb_sig}
-        coalesced = {b for b in _coalesced_buffers(seg)
-                     if b in glb_shapes and glb_shapes[b][0] == (B * T,)}
-        written_order = tuple(sorted(seg.gwrites))
-        has_shared = shared_sig is not None
-        S = shared_sig[0][1] if has_shared else 0
-        new_regs = tuple(sorted(r.name for r in seg.defs
-                                if r.name not in reg_names))
-        new_dt = {r.name: ir.np_dtype(r.dtype) for r in seg.defs
-                  if r.name in new_regs}
-
-        row_spec = pl.BlockSpec((1, T), lambda b: (b, 0))
-
-        in_specs: List[pl.BlockSpec] = [row_spec] * len(reg_names)
-        if has_shared:
-            in_specs.append(pl.BlockSpec((1, S), lambda b: (b, 0)))
-        for n in glb_names:
-            if n in coalesced:
-                in_specs.append(pl.BlockSpec((T,), lambda b: (b,)))
-            else:
-                in_specs.append(pl.BlockSpec(glb_shapes[n][0],
-                                             lambda b: (0,)))
-
-        out_specs: List[pl.BlockSpec] = []
-        out_shapes: List[jax.ShapeDtypeStruct] = []
-        for n in reg_names:
-            out_specs.append(row_spec)
-            out_shapes.append(jax.ShapeDtypeStruct((B, T), reg_dtypes[n]))
-        for n in new_regs:
-            out_specs.append(row_spec)
-            out_shapes.append(jax.ShapeDtypeStruct((B, T), new_dt[n]))
-        if has_shared:
-            out_specs.append(pl.BlockSpec((1, S), lambda b: (b, 0)))
-            out_shapes.append(jax.ShapeDtypeStruct((B, S), shared_sig[1]))
-        for n in written_order:
-            shape, dt = glb_shapes[n]
-            if n in coalesced:
-                out_specs.append(pl.BlockSpec((T,), lambda b: (b,)))
-            else:
-                out_specs.append(pl.BlockSpec(shape, lambda b: (0,)))
-            out_shapes.append(jax.ShapeDtypeStruct(shape, dt))
-
-        n_in = len(reg_names) + int(has_shared) + len(glb_names)
-
-        def kernel(*refs):
-            in_refs, out_refs = refs[:n_in], refs[n_in:]
-            b = pl.program_id(0)
-
-            reg_in = dict(zip(reg_names, in_refs[:len(reg_names)]))
-            sh_ref = in_refs[len(reg_names)] if has_shared else None
-            glb_in = dict(zip(glb_names,
-                              in_refs[len(reg_names) + int(has_shared):]))
-            out_reg_refs = dict(zip(reg_names + new_regs, out_refs))
-            o = len(reg_names) + len(new_regs)
-            out_sh_ref = out_refs[o] if has_shared else None
-            out_glb_refs = dict(zip(written_order,
-                                    out_refs[o + int(has_shared):]))
-
-            # revisited-output init for written, non-coalesced buffers
-            for n in written_order:
-                if n not in coalesced:
-                    @pl.when(b == 0)
-                    def _init(n=n):
-                        out_glb_refs[n][...] = glb_in[n][...]
-
-            glbs = {}
-            for n in glb_names:
-                if n in written_order and n not in coalesced:
-                    glbs[n] = out_glb_refs[n][...]
-                else:
-                    glbs[n] = glb_in[n][...]
-
-            env = Env(regs={k: v[...] for k, v in reg_in.items()},
-                      shared=sh_ref[...] if has_shared else None,
-                      globals_=glbs, scalars=scalars,
-                      num_blocks=B, block_size=T, block_offset=b)
-            env.lane_shape = (1, T)
-            env.coalesced = coalesced
-            env.tile_base = b * T
-            eval_stmts(seg.stmts, env, mask=None)
-
-            for k, ref in out_reg_refs.items():
-                if k in env.regs:
-                    ref[...] = jnp.broadcast_to(
-                        env.regs[k], (1, T)).astype(ref.dtype)
-                elif k in reg_in:  # untouched register: pass through
-                    ref[...] = reg_in[k][...]
-                else:  # defined only in a zero-trip loop: zeros
-                    ref[...] = jnp.zeros((1, T), ref.dtype)
-            if has_shared:
-                out_sh_ref[...] = env.shared.reshape(1, S)
-            for n in written_order:
-                out_glb_refs[n][...] = env.globals[n]
-
-        call = pl.pallas_call(
-            kernel,
-            grid=(B,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shapes,
-            interpret=self.interpret,
-        )
-        meta = dict(reg_names=reg_names, new_regs=new_regs,
-                    glb_names=glb_names, written=written_order,
-                    has_shared=has_shared, coalesced=coalesced,
-                    block=None, block_reason=block_reason)
-        example = tuple(
-            [jax.ShapeDtypeStruct(shape, np.dtype(dt))
-             for _, shape, dt in reg_sig]
-            + ([jax.ShapeDtypeStruct(shared_sig[0], np.dtype(shared_sig[1]))]
-               if has_shared else [])
-            + [jax.ShapeDtypeStruct(shape, np.dtype(dt))
-               for _, shape, dt in glb_sig])
-        fn, payload = export_translation(jax.jit(call), example,
-                                         cache=self.cache)
+        """Emit, trace, and export the segment's kernel.  Returns
+        ``((compiled fn, meta), persist)`` for the translation cache; the
+        persisted payload is the serialized ``jax.export`` artifact plus
+        ``meta``, so a warm process skips re-emitting and re-tracing."""
+        fn, meta, example = emit_segment(seg, launch, reg_sig, glb_sig,
+                                         shared_sig, block_cand,
+                                         interpret=pallas_interpret())
+        live, payload = export_translation(jax.jit(fn), example,
+                                           cache=self.cache)
         persist = None if payload is None \
             else ("jax-aot-meta", (payload, meta))
-        return (fn, meta), persist
-
-    def _build_block(self, plan: BlockPlan, seg: SegNode, launch: Launch,
-                     reg_sig: Tuple, glb_sig: Tuple):
-        """Block-tiled lowering of a proven lane-independent segment: one
-        ``pl.pallas_call`` whose grid walks ``N // BLOCK`` flat element
-        tiles.  Registers are ``[1, N]`` flat arrays (``run_segment``
-        reshapes the host-state ``[B, T]`` view; row-major flatten makes
-        lane ``gid = b * T + t`` land at flat position ``gid``); tiled
-        buffers get one ``(BLOCK,)`` tile per grid step, gather buffers are
-        staged whole with the revisited-output accumulator when written.
-        The segment is proven free of shared memory, so hetIR shared state
-        (if any) bypasses the kernel untouched."""
-        B, T = launch.num_blocks, launch.block_size
-        N = B * T
-        BLOCK, grid = plan.block, plan.grid
-        scalars = dict(launch.scalars)
-        reg_names = tuple(n for n, _, _ in reg_sig)
-        reg_dtypes = {n: dt for n, _, dt in reg_sig}
-        glb_names = tuple(n for n, _, _ in glb_sig)
-        glb_shapes = {n: (shape, dt) for n, shape, dt in glb_sig}
-        tiled = set(plan.tiled)
-        written_order = tuple(sorted(seg.gwrites))
-        new_regs = tuple(sorted(r.name for r in seg.defs
-                                if r.name not in reg_names))
-        new_dt = {r.name: ir.np_dtype(r.dtype) for r in seg.defs
-                  if r.name in new_regs}
-
-        row_spec = pl.BlockSpec((1, BLOCK), lambda i: (0, i))
-
-        in_specs: List[pl.BlockSpec] = [row_spec] * len(reg_names)
-        for n in glb_names:
-            if n in tiled:
-                in_specs.append(pl.BlockSpec((BLOCK,), lambda i: (i,)))
-            else:
-                in_specs.append(pl.BlockSpec(glb_shapes[n][0],
-                                             lambda i: (0,)))
-
-        out_specs: List[pl.BlockSpec] = []
-        out_shapes: List[jax.ShapeDtypeStruct] = []
-        for n in reg_names:
-            out_specs.append(row_spec)
-            out_shapes.append(jax.ShapeDtypeStruct((1, N), reg_dtypes[n]))
-        for n in new_regs:
-            out_specs.append(row_spec)
-            out_shapes.append(jax.ShapeDtypeStruct((1, N), new_dt[n]))
-        for n in written_order:
-            shape, dt = glb_shapes[n]
-            if n in tiled:
-                out_specs.append(pl.BlockSpec((BLOCK,), lambda i: (i,)))
-            else:
-                out_specs.append(pl.BlockSpec(shape, lambda i: (0,)))
-            out_shapes.append(jax.ShapeDtypeStruct(shape, dt))
-
-        n_in = len(reg_names) + len(glb_names)
-
-        def kernel(*refs):
-            in_refs, out_refs = refs[:n_in], refs[n_in:]
-            i = pl.program_id(0)
-
-            reg_in = dict(zip(reg_names, in_refs[:len(reg_names)]))
-            glb_in = dict(zip(glb_names, in_refs[len(reg_names):]))
-            out_reg_refs = dict(zip(reg_names + new_regs, out_refs))
-            o = len(reg_names) + len(new_regs)
-            out_glb_refs = dict(zip(written_order, out_refs[o:]))
-
-            # revisited-output init for written gather buffers
-            for n in written_order:
-                if n not in tiled:
-                    @pl.when(i == 0)
-                    def _init(n=n):
-                        out_glb_refs[n][...] = glb_in[n][...]
-
-            glbs = {}
-            for n in glb_names:
-                if n in written_order and n not in tiled:
-                    glbs[n] = out_glb_refs[n][...]
-                else:
-                    glbs[n] = glb_in[n][...]
-
-            env = Env(regs={k: v[...] for k, v in reg_in.items()},
-                      shared=None, globals_=glbs, scalars=scalars,
-                      num_blocks=B, block_size=T)
-            env.lane_shape = (1, BLOCK)
-            env.flat_base = i * BLOCK   # lanes are flat global-id tiles
-            env.coalesced = tiled       # tiled indices rebase to the tile
-            env.tile_base = i * BLOCK
-            eval_stmts(plan.stmts, env, mask=None)
-
-            for k, ref in out_reg_refs.items():
-                if k in env.regs:
-                    ref[...] = jnp.broadcast_to(
-                        env.regs[k], (1, BLOCK)).astype(ref.dtype)
-                elif k in reg_in:  # untouched register: pass through
-                    ref[...] = reg_in[k][...]
-                else:  # defined only in a zero-trip loop: zeros
-                    ref[...] = jnp.zeros((1, BLOCK), ref.dtype)
-            for n in written_order:
-                out_glb_refs[n][...] = env.globals[n]
-
-        call = pl.pallas_call(
-            kernel,
-            grid=(grid,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shapes,
-            interpret=self.interpret,
-        )
-        meta = dict(reg_names=reg_names, new_regs=new_regs,
-                    glb_names=glb_names, written=written_order,
-                    has_shared=False, coalesced=tiled,
-                    block=dict(block=BLOCK, grid=grid,
-                               tiled=tuple(sorted(tiled))),
-                    block_reason=None)
-        example = tuple(
-            [jax.ShapeDtypeStruct((1, N), np.dtype(dt))
-             for _, _, dt in reg_sig]
-            + [jax.ShapeDtypeStruct(shape, np.dtype(dt))
-               for _, shape, dt in glb_sig])
-        fn, payload = export_translation(jax.jit(call), example,
-                                         cache=self.cache)
-        persist = None if payload is None \
-            else ("jax-aot-meta", (payload, meta))
-        return (fn, meta), persist
+        return (live, meta), persist
 
     # ------------------------------------------------------------------
     def run_segment(self, seg: SegNode, state: HostState,
                     launch: Launch) -> None:
         reg_sig, glb_sig, shared_sig = state_signature(state)
-        reg_names = tuple(n for n, _, _ in reg_sig)
-        glb_names = tuple(n for n, _, _ in glb_sig)
-
         call, meta = self._translate(seg, launch, reg_sig, glb_sig,
                                      shared_sig)
-
-        blk = meta.get("block")
-        if blk is not None:
+        if meta["block"] is not None:
             self.block_stats["tiled"] += 1
-            B, T = launch.num_blocks, launch.block_size
-            N = B * T
-            # registers travel flat: [B, T] row-major == lane gid order
-            args = [jnp.asarray(state.regs[n]).reshape(1, N)
-                    for n in reg_names]
-            args += [jnp.asarray(state.globals_[n]) for n in glb_names]
-            outs = call(*args)
-            i = 0
-            regs = {}
-            for n in meta["reg_names"] + meta["new_regs"]:
-                regs[n] = outs[i].reshape(B, T)
-                i += 1
-            state.regs = regs
-            for n in meta["written"]:
-                state.globals_[n] = outs[i]
-                i += 1
-            # shared memory provably untouched by a block-lowered segment
-            return
-        self.block_stats["scalar"] += 1
-        reason = meta.get("block_reason")
-        if reason:
-            # histogram on the *stable category* only (passes.
-            # REFUSAL_REASONS); the free-form detail suffix (buffer name,
-            # opcode) stays in meta["block_reason"] for diagnostics but
-            # must not leak into the stats surface gates key on
-            rs = self.block_stats["reasons"]
-            cat = refusal_category(reason)
-            rs[cat] = rs.get(cat, 0) + 1
+        else:
+            self.block_stats["scalar"] += 1
+            reason = meta.get("block_reason")
+            if reason:
+                # histogram on the *stable category* only (passes.
+                # REFUSAL_REASONS); the free-form detail suffix stays in
+                # meta["block_reason"] for diagnostics
+                rs = self.block_stats["reasons"]
+                cat = refusal_category(reason)
+                rs[cat] = rs.get(cat, 0) + 1
 
-        args = [jnp.asarray(state.regs[n]) for n in reg_names]
+        loops = np.array([np.asarray(state.regs[v]).reshape(-1)[0]
+                          for v in meta["loop_vars"]] or [0], np.int32)
+        # globals stay device-resident between segments
+        for n in meta["glb_names"]:
+            state.globals_[n] = jnp.asarray(state.globals_[n])
+        args = [loops] + [state.regs[n] for n in meta["reg_io"]]
         if meta["has_shared"]:
-            args.append(jnp.asarray(state.shared))
-        args += [jnp.asarray(state.globals_[n]) for n in glb_names]
-
-        outs = call(*args)
-        i = 0
-        regs = {}
-        for n in meta["reg_names"] + meta["new_regs"]:
-            regs[n] = outs[i]  # stays on device between segments
-            i += 1
+            args.append(state.shared)
+        args += [state.globals_[n] for n in meta["glb_names"]]
+        outs = list(call(*args))
+        regs = {v: state.regs[v] for v in meta["loop_vars"]}
+        for n in meta["reg_io"] + meta["new_regs"]:
+            regs[n] = outs.pop(0)  # stays on device between segments
         state.regs = regs
         if meta["has_shared"]:
-            state.shared = outs[i]
-            i += 1
+            state.shared = outs.pop(0)
         for n in meta["written"]:
-            state.globals_[n] = outs[i]
-            i += 1
+            state.globals_[n] = outs.pop(0)

@@ -46,17 +46,24 @@ class Env:
         self.block_size = block_size
         self.block_offset = block_offset  # first block id of the lane arrays
         self.lane_shape: Optional[Tuple[int, ...]] = None  # set on first use
-        # Pallas fast path: buffers tiled per block (indices are global ids
-        # and must be rebased to tile-local offsets).  Empty for other
-        # backends.
-        self.coalesced: set = set()
-        self.tile_base = 0
         # Block-tiled fast path: when set, lane arrays are flat element
         # tiles ``[1, BLOCK]`` starting at this flat global id instead of
         # ``[rows, block_size]`` — thread identity is reconstructed from
         # ``gid = flat_base + iota`` (sound only for segments proven
         # lane-independent by ``passes.block_lower``).
         self.flat_base: Optional[Any] = None
+        # Memory accessor for the pallas kernel body
+        # (:class:`~repro.core.backends.pallas_mem.RefMemory`): global and
+        # shared accesses go through VMEM refs with lowerable gathers and
+        # scatters.  ``None`` keeps the whole-array semantics below.
+        self.mem = None
+        # float division (see _fdiv): the divisor's optimization_barrier
+        # exists for XLA's simplifier (Mosaic kernel bodies cannot lower
+        # it and never run that simplifier), and the quotient needs a
+        # software correction only where the divider is not IEEE — every
+        # target but the CPU
+        self.div_barrier = True
+        self.ieee_div = jax.default_backend() == "cpu"
 
     def write_reg(self, reg: ir.Reg, value, mask):
         value = jnp.asarray(value, dtype=ir.np_dtype(reg.dtype))
@@ -67,20 +74,36 @@ class Env:
                 # hetIR registers read as zero until first written; a
                 # masked first write must leave inactive lanes at zero
                 # (matches the interpreter's per-lane zero-fill).
-                old = jnp.zeros(self.lane_shape, ir.np_dtype(reg.dtype))
+                old = _full(self.lane_shape, 0, ir.np_dtype(reg.dtype))
             else:
                 old = jnp.broadcast_to(
                     jnp.asarray(old, dtype=ir.np_dtype(reg.dtype)),
                     self.lane_shape)
-            value = jnp.where(mask, value, old)
+            value = _select(mask, value, old)
         self.regs[reg.name] = value
 
     def read_reg(self, reg: ir.Reg):
         v = self.regs.get(reg.name)
         if v is None:  # never-written register: reads as zero
-            return jnp.zeros(self.lane_shape, ir.np_dtype(reg.dtype))
+            return _full(self.lane_shape, 0, ir.np_dtype(reg.dtype))
         return jnp.broadcast_to(jnp.asarray(v, ir.np_dtype(reg.dtype)),
                                 self.lane_shape)
+
+
+def _full(shape, value, dtype):
+    """``jnp.full``; boolean vectors come from an int32 compare (Mosaic
+    cannot materialise a boolean vector constant)."""
+    if np.dtype(dtype) == np.bool_:
+        return jnp.full(shape, int(bool(value)), jnp.int32) != 0
+    return jnp.full(shape, value, dtype)
+
+
+def _select(c, a, b):
+    """``jnp.where``; boolean operands select through logic ops (Mosaic
+    cannot select between boolean vectors)."""
+    if jnp.result_type(a) == jnp.bool_ and jnp.result_type(b) == jnp.bool_:
+        return (c & a) | (~c & b)
+    return jnp.where(c, a, b)
 
 
 def _lane_ids(env: Env):
@@ -148,18 +171,24 @@ def eval_op(op: ir.Op, env: Env, mask) -> None:
 
     # ---- constants / moves ------------------------------------------------
     elif oc == ir.CONST:
-        env.write_reg(d, jnp.full(env.lane_shape, op.args[0],
-                                  ir.np_dtype(d.dtype)), mask)
+        env.write_reg(d, _full(env.lane_shape, op.args[0],
+                               ir.np_dtype(d.dtype)), mask)
     elif oc == ir.LD_PARAM:
-        env.write_reg(d, jnp.full(env.lane_shape, env.scalars[op.args[0]],
-                                  ir.np_dtype(d.dtype)), mask)
+        env.write_reg(d, _full(env.lane_shape, env.scalars[op.args[0]],
+                               ir.np_dtype(d.dtype)), mask)
     elif oc == ir.MOV:
         env.write_reg(d, _arg(env, op.args[0]), mask)
     elif oc == ir.CVT:
-        env.write_reg(d, _arg(env, op.args[0]).astype(
-            ir.np_dtype(d.dtype)), mask)
+        env.write_reg(d, _convert(_arg(env, op.args[0]),
+                                  ir.np_dtype(d.dtype)), mask)
 
     # ---- ALU ---------------------------------------------------------------
+    elif oc == ir.DIV:
+        a = _arg(env, op.args[0])
+        b = _arg(env, op.args[1])
+        env.write_reg(d, _int_or_float(
+            a, b, lambda x, y: x // y,
+            lambda x, y: _fdiv(x, y, env.div_barrier, env.ieee_div)), mask)
     elif oc in _BINOPS:
         a = _arg(env, op.args[0])
         b = _arg(env, op.args[1])
@@ -171,65 +200,82 @@ def eval_op(op: ir.Op, env: Env, mask) -> None:
         env.write_reg(d, _pin(_mul_exact(a, b) + c), mask)
     elif oc == ir.SELECT:
         c, a, b = (_arg(env, x) for x in op.args)
-        env.write_reg(d, jnp.where(c, a, b), mask)
+        env.write_reg(d, _select(c, a, b), mask)
 
     # ---- global memory -----------------------------------------------------
     # BLOCK_LD/BLOCK_ST evaluate exactly like their scalar forms — the tile
     # geometry in their attrs steers BlockSpec construction in the pallas
-    # backend, not the per-lane value semantics ("tiled" buffers are rebased
-    # via env.coalesced/tile_base like any coalesced buffer).
+    # backend (whose memory accessor reads a "tiled" buffer's own tile),
+    # not the per-lane value semantics.
     elif oc in (ir.LD_GLOBAL, ir.BLOCK_LD):
-        buf = env.globals[op.args[0]]
-        idx = _global_idx(env, op.args[0], op.args[1])
-        safe = idx if mask is None else jnp.where(mask, idx, 0)
-        env.write_reg(d, jnp.take(buf, safe.reshape(-1), axis=0)
-                      .reshape(env.lane_shape), mask)
+        idx = _arg(env, op.args[1]).astype(jnp.int32)
+        if env.mem is not None:
+            env.write_reg(d, env.mem.load(op, idx, mask), mask)
+        else:
+            buf = env.globals[op.args[0]]
+            safe = idx if mask is None else jnp.where(mask, idx, 0)
+            env.write_reg(d, jnp.take(buf, safe.reshape(-1), axis=0)
+                          .reshape(env.lane_shape), mask)
     elif oc in (ir.ST_GLOBAL, ir.BLOCK_ST):
-        buf = env.globals[op.args[0]]
-        idx = _global_idx(env, op.args[0], op.args[1])
-        val = _arg(env, op.args[2]).astype(buf.dtype)
-        val = jnp.broadcast_to(val, env.lane_shape)
-        oob = jnp.int32(buf.shape[0])
-        safe = idx if mask is None else jnp.where(mask, idx, oob)
-        env.globals[op.args[0]] = buf.at[safe.reshape(-1)].set(
-            val.reshape(-1), mode="drop")
+        idx = _arg(env, op.args[1]).astype(jnp.int32)
+        val = jnp.broadcast_to(_arg(env, op.args[2]), env.lane_shape)
+        if env.mem is not None:
+            env.mem.store(op, idx, val, mask)
+        else:
+            buf = env.globals[op.args[0]]
+            oob = jnp.int32(buf.shape[0])
+            safe = idx if mask is None else jnp.where(mask, idx, oob)
+            env.globals[op.args[0]] = buf.at[safe.reshape(-1)].set(
+                val.astype(buf.dtype).reshape(-1), mode="drop")
     elif oc == ir.ATOMIC_ADD:
-        buf = env.globals[op.args[0]]
-        idx = _global_idx(env, op.args[0], op.args[1])
-        val = _arg(env, op.args[2]).astype(buf.dtype)
-        val = jnp.broadcast_to(val, env.lane_shape)
-        oob = jnp.int32(buf.shape[0])
-        safe = idx if mask is None else jnp.where(mask, idx, oob)
-        old = jnp.take(buf, jnp.where(safe >= oob, 0, safe).reshape(-1),
-                       axis=0).reshape(env.lane_shape)
-        env.globals[op.args[0]] = buf.at[safe.reshape(-1)].add(
-            val.reshape(-1), mode="drop")
+        idx = _arg(env, op.args[1]).astype(jnp.int32)
+        val = jnp.broadcast_to(_arg(env, op.args[2]), env.lane_shape)
+        if env.mem is not None:
+            old = env.mem.atomic_add(op, idx, val, mask)
+        else:
+            buf = env.globals[op.args[0]]
+            val = val.astype(buf.dtype)
+            oob = jnp.int32(buf.shape[0])
+            safe = idx if mask is None else jnp.where(mask, idx, oob)
+            old = jnp.take(buf, jnp.where(safe >= oob, 0, safe).reshape(-1),
+                           axis=0).reshape(env.lane_shape)
+            env.globals[op.args[0]] = buf.at[safe.reshape(-1)].add(
+                val.reshape(-1), mode="drop")
         if d is not None:
             env.write_reg(d, old, mask)
 
     # ---- shared memory -----------------------------------------------------
     elif oc == ir.LD_SHARED:
         idx = _arg(env, op.args[0]).astype(jnp.int32)
-        safe = idx if mask is None else jnp.where(mask, idx, 0)
-        env.write_reg(d, jnp.take_along_axis(env.shared, safe, axis=1), mask)
+        if env.mem is not None:
+            env.write_reg(d, env.mem.load_shared(idx, mask), mask)
+        else:
+            safe = idx if mask is None else jnp.where(mask, idx, 0)
+            env.write_reg(d, jnp.take_along_axis(env.shared, safe, axis=1),
+                          mask)
     elif oc == ir.ST_SHARED:
         idx = _arg(env, op.args[0]).astype(jnp.int32)
-        val = _arg(env, op.args[1]).astype(env.shared.dtype)
-        val = jnp.broadcast_to(val, env.lane_shape)
-        oob = jnp.int32(env.shared.shape[1])
-        safe = idx if mask is None else jnp.where(mask, idx, oob)
-        rows = jax.lax.broadcasted_iota(jnp.int32, env.lane_shape, 0)
-        env.shared = env.shared.at[rows.reshape(-1), safe.reshape(-1)].set(
-            val.reshape(-1), mode="drop")
+        val = jnp.broadcast_to(_arg(env, op.args[1]), env.lane_shape)
+        if env.mem is not None:
+            env.mem.store_shared(idx, val, mask)
+        else:
+            val = val.astype(env.shared.dtype)
+            oob = jnp.int32(env.shared.shape[1])
+            safe = idx if mask is None else jnp.where(mask, idx, oob)
+            rows = jax.lax.broadcasted_iota(jnp.int32, env.lane_shape, 0)
+            env.shared = env.shared.at[rows.reshape(-1),
+                                       safe.reshape(-1)].set(
+                val.reshape(-1), mode="drop")
 
     # ---- collectives (within block, over active lanes) ----------------------
     elif oc == ir.VOTE_ANY:
-        p = _active(_arg(env, op.args[0]), mask)
-        env.write_reg(d, jnp.any(p, axis=-1, keepdims=True), mask)
+        p = _active(_arg(env, op.args[0]), mask).astype(jnp.int32)
+        env.write_reg(d, jnp.max(p, axis=-1, keepdims=True) > 0, mask)
     elif oc == ir.VOTE_ALL:
         p = _arg(env, op.args[0])
         p = p if mask is None else jnp.logical_or(p, jnp.logical_not(mask))
-        env.write_reg(d, jnp.all(p, axis=-1, keepdims=True), mask)
+        env.write_reg(d, jnp.min(p.astype(jnp.int32), axis=-1,
+                                 keepdims=True) > 0, mask)
     elif oc == ir.VOTE_BALLOT:
         p = _active(_arg(env, op.args[0]), mask)
         env.write_reg(d, jnp.sum(p.astype(jnp.int32), axis=-1,
@@ -251,18 +297,11 @@ def eval_op(op: ir.Op, env: Env, mask) -> None:
         v = _arg(env, op.args[0])
         src = _arg(env, op.args[1]).astype(jnp.int32)
         src = jnp.clip(src, 0, env.block_size - 1)
-        env.write_reg(d, jnp.take_along_axis(v, src, axis=-1), mask)
+        take = jnp.take_along_axis if env.mem is None else env.mem.lane_take
+        env.write_reg(d, take(v, src, axis=-1), mask)
 
     else:  # pragma: no cover
         raise NotImplementedError(oc)
-
-
-def _global_idx(env: Env, buf_name: str, idx_arg):
-    """Index into a global buffer, rebased for per-block tiles (Pallas)."""
-    idx = _arg(env, idx_arg).astype(jnp.int32)
-    if buf_name in env.coalesced:
-        idx = idx - jnp.asarray(env.tile_base, jnp.int32)
-    return idx
 
 
 def _seq_reduce_add(v):
@@ -297,6 +336,17 @@ def _seq_scan_add(v):
     for t in range(1, v.shape[-1]):
         cols.append(_pin(cols[-1] + v[..., t:t + 1]))
     return jnp.concatenate(cols, axis=-1)
+
+
+def _convert(v, dt):
+    """``v.astype(dt)``, with u32 -> float built from two exact i32 halves
+    and one rounding of their sum (the same correctly rounded value): the
+    TPU's Mosaic compiler has no unsigned-to-float conversion."""
+    if v.dtype == jnp.uint32 and jnp.issubdtype(dt, jnp.floating):
+        hi = (v >> 16).astype(jnp.int32).astype(dt)
+        lo = (v & 0xFFFF).astype(jnp.int32).astype(dt)
+        return _pin(_pin(hi * dt.type(65536.0)) + lo)
+    return v.astype(dt)
 
 
 def _active(pred, mask):
@@ -340,20 +390,75 @@ def _mul_exact(a, b):
     return _pin(a * b)
 
 
+def _split(a):
+    """Dekker split: ``a == hi + lo`` exactly, each half 12 bits wide."""
+    c = _pin(a * a.dtype.type(4097.0))
+    hi = _pin(c - _pin(c - a))
+    return hi, _pin(a - hi)
+
+
+def _prod_err(a, b, p):
+    """``a*b - p`` exactly, for ``p = RN(a*b)`` (Dekker's TwoProduct)."""
+    ah, al = _split(a)
+    bh, bl = _split(b)
+    e = _pin(_pin(ah * bh) - p)
+    e = _pin(e + _pin(ah * bl))
+    e = _pin(e + _pin(al * bh))
+    return _pin(e + _pin(al * bl))
+
+
+#: |x|, |y| and |x/y| inside this range keep every intermediate of the
+#: division correction a normal float (no overflow, no flushed subnormal)
+_DIV_SAFE = (2.0 ** -60, 2.0 ** 60)
+
+
+def _fdiv(x, y, barrier: bool, ieee: bool):
+    """Correctly rounded float division from the hardware quotient.
+
+    The divisor hides behind an optimization_barrier under XLA (which
+    strength-reduces division by a *constant* into multiply-by-reciprocal,
+    ~15% of inputs 1 ULP off; found by the attention-profile fuzz corpus,
+    seed 20260860: x / 3.1415927).  Mosaic kernel bodies cannot lower the
+    barrier and never run that simplifier (``barrier=False``).
+
+    A TPU has no IEEE divider (up to 2 ULP off, measured on a v5 lite),
+    so unless ``ieee`` the hardware quotient ``q`` is corrected twice
+    toward the round-to-nearest-even result: the residual ``r = x - q*y``
+    is exact (Sterbenz for ``x - RN(q*y)``, Dekker for the product's
+    error), and ``q`` moves to its neighbour when ``|r|`` exceeds half
+    the gap times ``|y|`` (ties to even).  Operands or quotients outside
+    :data:`_DIV_SAFE`, zeros and non-finite values keep the hardware
+    quotient."""
+    q = _pin(x / (jax.lax.optimization_barrier(y) if barrier else y))
+    return q if ieee else _round_quotient(x, y, q)
+
+
+def _round_quotient(x, y, q):
+    """``RN(x / y)`` from a quotient ``q`` within two ULP of it (see
+    :func:`_fdiv`)."""
+    lo, hi = (x.dtype.type(v) for v in _DIV_SAFE)
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    safe = (ax >= lo) & (ax <= hi) & (ay >= lo) & (ay <= hi)
+    inf = x.dtype.type(np.inf)
+    for _ in range(2):
+        aq = jnp.abs(q)
+        ok = safe & (aq >= lo) & (aq <= hi)
+        p = _pin(q * y)
+        r = _pin(_pin(x - p) - _prod_err(q, y, p))
+        up = (r > 0) == (y > 0)            # the true quotient lies above q
+        nb = jnp.where(up, jnp.nextafter(q, inf), jnp.nextafter(q, -inf))
+        t = _pin(_pin(jnp.abs(_pin(nb - q)) * x.dtype.type(0.5)) * ay)
+        ar = jnp.abs(r)
+        odd = (jax.lax.bitcast_convert_type(q, jnp.int32) & 1) != 0
+        move = ok & (r != 0) & ((ar > t) | ((ar == t) & odd))
+        q = jnp.where(move, nb, q)
+    return q
+
+
 _BINOPS = {
     ir.ADD: lambda a, b: _pin(a + b),
     ir.SUB: lambda a, b: _pin(a - b),
     ir.MUL: _mul_exact,
-    # the float divisor hides behind an optimization_barrier: XLA
-    # strength-reduces division by a *constant* into multiply-by-
-    # reciprocal (~15% of inputs off by 1 ULP vs a true IEEE divide;
-    # _pin can't help — the rewrite happens at the div, not after it).
-    # The barrier makes the divisor opaque to the algebraic simplifier,
-    # so the true division survives.  Found by the attention-profile
-    # cross-backend fuzz corpus (seed 20260860: x / 3.1415927).
-    ir.DIV: lambda a, b: _int_or_float(
-        a, b, lambda x, y: x // y,
-        lambda x, y: _pin(x / jax.lax.optimization_barrier(y))),
     ir.MOD: lambda a, b: a % b,
     ir.MIN: jnp.minimum,
     ir.MAX: jnp.maximum,

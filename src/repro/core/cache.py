@@ -132,11 +132,9 @@ def _runtime_tag() -> str:
     unrolling thresholds — a pass-set change retires the whole directory,
     so a stale artifact optimized by an older pipeline is never restored
     against a program the current pipeline would optimize differently."""
-    try:
-        import jax
-        jv, plat = jax.__version__, jax.default_backend()
-    except Exception:  # pragma: no cover - jax is a baked-in dependency
-        jv, plat = "nojax", "cpu"
+    import jax
+
+    jv, plat = jax.__version__, jax.default_backend()
     from .passes import pipeline_fingerprint
     return f"v{STORE_FORMAT_VERSION}-p{pipeline_fingerprint()}-jax{jv}-{plat}"
 

@@ -74,6 +74,43 @@ MID_KERNEL = "mid-kernel"
 POST_CHECKPOINT_PRE_ACK = "post-checkpoint-pre-ack"
 FAULT_POINTS = (PRE_LAUNCH, MID_KERNEL, POST_CHECKPOINT_PRE_ACK)
 
+#: backends whose workers compile for and run on the accelerator
+_DEVICE_BACKENDS = ("vectorized", "pallas")
+#: first of the per-chip ports one-chip TPU worker processes listen on
+_TPU_PORT_BASE = 8476
+
+
+def tpu_chip_count() -> int:
+    """TPU chips attached to this host, counted on the PCI bus the way JAX
+    itself decides whether a TPU is present — no backend is initialised,
+    so the process asking (a coordinator) never takes a chip."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+class _child_env:
+    """Context manager: ``os.environ`` overlaid with ``env`` while a
+    worker is spawned (the child starts from a copy, before it imports
+    anything), restored afterwards."""
+
+    def __init__(self, env: Dict[str, str]):
+        self.env = env
+        self.saved: Dict[str, Optional[str]] = {}
+
+    def __enter__(self):
+        for k, v in self.env.items():
+            self.saved[k] = os.environ.get(k)
+            os.environ[k] = v
+
+    def __exit__(self, *exc):
+        for k, v in self.saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 #: default per-RPC timeout: a wedged worker fails loudly, never hangs CI
 _DEFAULT_RPC_TIMEOUT = float(os.environ.get("HETGPU_FLEET_TIMEOUT", "60"))
 
@@ -532,9 +569,16 @@ def _worker_main(worker_id: int, conn, config: Dict) -> None:
                                                              0)),
                                  "outputs": list(payload["outputs"])}
                 conn.send(("ok", {"finished": rec.finished}))
+            elif cmd == "warmup":
+                progs = [f.program for f in session._functions.values()]
+                conn.send(("ok", {"report": session.warmup(
+                    progs, grids=[tuple(g) for g in payload["grids"]])}))
             elif cmd == "ping":
-                conn.send(("ok", {"pid": os.getpid(),
-                                  "inflight": len(launches)}))
+                reply = {"pid": os.getpid(), "inflight": len(launches)}
+                if payload.get("device"):
+                    from ..device import device_info
+                    reply["device"] = device_info()
+                conn.send(("ok", reply))
             elif cmd == "stats":
                 conn.send(("ok", {
                     "inflight": len(launches),
@@ -560,7 +604,7 @@ def _worker_main(worker_id: int, conn, config: Dict) -> None:
 
 class _Worker:
     __slots__ = ("wid", "proc", "conn", "backend", "alive", "draining",
-                 "launches", "_rr")
+                 "launches", "_rr", "chip")
 
     def __init__(self, wid: int, proc, conn, backend: str):
         self.wid = wid
@@ -571,6 +615,7 @@ class _Worker:
         self.draining = False
         self.launches: List[str] = []   # dispatch order
         self._rr = 0                    # round-robin cursor
+        self.chip: Optional[int] = None  # TPU chip held, on a TPU host
 
     def next_launch(self) -> Optional[str]:
         if not self.launches:
@@ -723,9 +768,12 @@ class FleetCoordinator:
                "fault_seed": self.fault_seed}
         proc = self._ctx.Process(target=_worker_main,
                                  args=(wid, child, cfg), daemon=True)
-        proc.start()
+        chip, env = self._chip_for(backend)
+        with _child_env(env):
+            proc.start()
         child.close()
         w = _Worker(wid, proc, parent, backend)
+        w.chip = chip
         self.workers[wid] = w
         self.counters["workers_spawned"] += 1
         status, _ = self._recv(w)       # "ready" handshake
@@ -734,6 +782,29 @@ class FleetCoordinator:
         for blob in dict.fromkeys(self._programs.values()):
             self._rpc(w, "load", {"blob": blob})
         return wid
+
+    def _chip_for(self, backend: str) -> Tuple[Optional[int],
+                                                Dict[str, str]]:
+        """``(chip, environment)`` for the next spawned worker.  On a TPU
+        host a chip belongs to one process: each worker of a device
+        backend gets the lowest chip no live worker holds, and host-only
+        (``interp``) workers are kept off the chips.  Elsewhere nothing
+        changes."""
+        chips = tpu_chip_count()
+        if not chips:
+            return None, {}
+        if backend not in _DEVICE_BACKENDS:
+            return None, {"JAX_PLATFORMS": "cpu"}
+        held = {w.chip for w in self.workers.values() if w.alive}
+        free = [c for c in range(chips) if c not in held]
+        if not free:
+            raise FleetError(f"no free TPU chip for a {backend} worker "
+                             f"({chips} chips, all held)")
+        chip = free[0]
+        return chip, {"TPU_VISIBLE_CHIPS": str(chip),
+                      "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                      "TPU_PROCESS_BOUNDS": "1,1,1",
+                      "TPU_PROCESS_PORT": str(_TPU_PORT_BASE + chip)}
 
     def shutdown(self) -> None:
         """Stop every worker (graceful first, SIGKILL stragglers).  The
@@ -779,10 +850,12 @@ class FleetCoordinator:
     def prewarm(self, grids: Sequence[Tuple[int, int]] = ((2, 32),),
                 backends: Optional[Sequence[str]] = None) -> Dict[str, object]:
         """Pre-publish translations for every registered kernel into the
-        cluster fabric, in-process, before any worker touches them — the
-        coordinator pays the one fleet-wide translation up front, and
-        every worker (current and future, here and on other hosts sharing
-        the fabric) warm-starts from the published AOT executables.
+        cluster fabric before any launch needs them: one alive worker per
+        backend runs :meth:`HetSession.warmup` and publishes what it
+        translates, and every other worker (current and future, here and
+        on other hosts sharing the fabric) warm-starts from the published
+        AOT executables.  The coordinator itself never compiles: on a TPU
+        host that would take a chip a worker needs.
 
         ``backends`` defaults to the distinct backends of the current
         workers.  Requires a ``shared_dir`` (without a fabric there is
@@ -792,17 +865,16 @@ class FleetCoordinator:
             raise FleetError("prewarm needs a cluster fabric: construct the "
                              "coordinator with shared_dir= (or set "
                              "HETGPU_CACHE_SHARED_DIR)")
-        from .runtime import HetSession
+        alive = self._alive()
         if backends is None:
-            backends = sorted({w.backend for w in self.workers.values()})
-        programs = [pickle.loads(blob)
-                    for blob in dict.fromkeys(self._programs.values())]
-        flat = [p for group in programs for p in group]
+            backends = sorted({w.backend for w in alive})
         report: Dict[str, object] = {}
         for backend in backends:
-            session = HetSession(backend, opt_level=self.opt_level,
-                                 shared=self.shared_dir)
-            report[backend] = session.warmup(flat, grids=grids)
+            w = next((w for w in alive if w.backend == backend), None)
+            if w is None:
+                raise FleetError(f"prewarm: no alive {backend} worker")
+            _, reply = self._rpc(w, "warmup", {"grids": list(grids)})
+            report[backend] = reply["report"]
         return report
 
     # -- submission ------------------------------------------------------

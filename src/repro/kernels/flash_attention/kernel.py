@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...device import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -97,7 +99,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window=None,
                         bq: int = 128, bk: int = 128,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q,k,v: [B, H, S, d] (kv pre-repeated for GQA).  Returns [B,H,S,d]."""
     B, H, Sq, d = q.shape
     Sk = k.shape[2]
@@ -127,5 +129,5 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
         ],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(q, k, v)

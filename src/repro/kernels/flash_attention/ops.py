@@ -17,7 +17,7 @@ from .ref import attention_ref
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True, window=None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q,k,v: [B,H,S,d] (repeat GQA kv to H heads first)."""
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                interpret=interpret)

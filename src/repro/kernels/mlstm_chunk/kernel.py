@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...device import pallas_interpret
+
 
 def _kernel(q_ref, k_ref, v_ref, lf_ref, i_ref, o_ref, cend_ref, c_ref, *,
             bt: int, nt: int):
@@ -77,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, lf_ref, i_ref, o_ref, cend_ref, c_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def mlstm_chunk_fwd(q, k, v, lf, gi, *, bt: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q,k: [BH, S, dk]; v: [BH, S, dv]; lf, gi: [BH, S, 1].
     Returns (y [BH,S,dv], C_final [BH,dk,dv])."""
     BH, S, dk = q.shape
@@ -105,5 +107,5 @@ def mlstm_chunk_fwd(q, k, v, lf, gi, *, bt: int = 128,
             jax.ShapeDtypeStruct((BH, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(q, k, v, lf, gi)

@@ -10,7 +10,7 @@ from .ref import mlstm_chunk_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def mlstm_chunk(q, k, v, lf, gi, interpret: bool = True):
+def mlstm_chunk(q, k, v, lf, gi, interpret: bool | None = None):
     return mlstm_chunk_fwd(q, k, v, lf, gi, interpret=interpret)
 
 

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...device import pallas_interpret
+
 
 def _kernel(x_ref, w_ref, counts_ref, o_ref, acc_ref, *, bc: int, nd: int):
     e = pl.program_id(0)
@@ -45,7 +47,7 @@ def _kernel(x_ref, w_ref, counts_ref, o_ref, acc_ref, *, bc: int, nd: int):
 
 @functools.partial(jax.jit, static_argnames=("bc", "bf", "bd", "interpret"))
 def moe_gmm_fwd(x, w, counts, *, bc: int = 128, bf: int = 128,
-                bd: int = 128, interpret: bool = True):
+                bd: int = 128, interpret: bool | None = None):
     """x: [E,C,D]; w: [E,D,F]; counts: [E] int32.  Returns [E,C,F]."""
     E, C, D = x.shape
     F = w.shape[-1]
@@ -65,5 +67,5 @@ def moe_gmm_fwd(x, w, counts, *, bc: int = 128, bf: int = 128,
                                lambda e, ci, fi, di: (e, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(x, w, counts)

@@ -10,7 +10,7 @@ from .ref import moe_gmm_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def moe_gmm(x, w, counts, interpret: bool = True):
+def moe_gmm(x, w, counts, interpret: bool | None = None):
     return moe_gmm_fwd(x, w, counts, interpret=interpret)
 
 

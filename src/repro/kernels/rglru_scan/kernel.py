@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...device import pallas_interpret
+
 
 def _kernel(a_ref, x_ref, h0_ref, o_ref, hend_ref, carry_ref, *,
             bs: int, ns: int, seq: int):
@@ -52,7 +54,7 @@ def _kernel(a_ref, x_ref, h0_ref, o_ref, hend_ref, carry_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bs", "bd", "interpret"))
 def rglru_scan_fwd(a, x, h0, *, bs: int = 256, bd: int = 128,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """a, x: [B, S, D] (decay, gated input); h0: [B, D].
     Returns (h [B,S,D], h_final [B,D])."""
     B, S, D = a.shape
@@ -79,5 +81,5 @@ def rglru_scan_fwd(a, x, h0, *, bs: int = 256, bd: int = 128,
             jax.ShapeDtypeStruct((B, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(a, x, h0)

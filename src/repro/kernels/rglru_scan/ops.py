@@ -12,7 +12,7 @@ from .ref import rglru_scan_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def rglru_scan(a, x, h0, interpret: bool = True):
+def rglru_scan(a, x, h0, interpret: bool | None = None):
     return rglru_scan_fwd(a, x, h0, interpret=interpret)
 
 

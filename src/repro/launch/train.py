@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --shape train_4k --steps 1000 --ckpt /path/ck [--multi-pod]
 
-On this CPU container only smoke-scale runs execute; on a real TPU slice
-the same entry point drives the production mesh (the mesh shape is the
-only difference — the model/runtime code is mesh-agnostic).
+On a CPU backend only smoke-scale runs are practical; on a TPU slice the
+same entry point drives the production mesh (the mesh shape is the only
+difference — the model/runtime code is mesh-agnostic).
 """
 import argparse
 
@@ -13,7 +13,7 @@ import jax
 
 from repro import configs
 from repro.configs.base import SHAPES, ShapeCfg, default_parallel
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.runtime.train_loop import Trainer
 
 
@@ -33,7 +33,7 @@ def main():
         cfg = configs.get_smoke_config(args.arch)
         shape = ShapeCfg("smoke", 64, 4, "train")
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = auto_mesh((n, 1), ("data", "model"))
     else:
         cfg = configs.get_config(args.arch)
         shape = SHAPES[args.shape]

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.launch.mesh import auto_mesh
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore, save
 from repro.configs.base import ParallelCfg, ShapeCfg
 from repro.runtime.train_loop import SimulatedFailure, Trainer
@@ -23,7 +24,7 @@ SMOKE_SHAPE = ShapeCfg("tiny", 32, 4, "train")
 
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_checkpoint_roundtrip(tmp_path):
@@ -94,6 +95,7 @@ import json, sys
 import jax
 import numpy as np
 from repro import configs
+from repro.launch.mesh import auto_mesh
 from repro.configs.base import ParallelCfg, ShapeCfg
 from repro.runtime.train_loop import Trainer
 
@@ -101,8 +103,8 @@ cfg = configs.get_smoke_config("llama3.2-3b")
 shape = ShapeCfg("tiny", 32, 8, "train")
 ckpt = sys.argv[1]
 
-mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = auto_mesh((2, 4), ("data", "model"))
+mesh_b = auto_mesh((4, 2), ("data", "model"))
 
 # reference: 4 steps on mesh A only
 t_ref = Trainer(cfg, shape, mesh_a, seed=11)

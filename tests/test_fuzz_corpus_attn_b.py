@@ -1,0 +1,10 @@
+"""Fixed-seed attn fuzz corpus, chunks 4-6 (the
+generator and the check live in :mod:`fuzz_corpus`)."""
+import pytest
+
+from fuzz_corpus import CHUNKS, run_corpus_chunk
+
+
+@pytest.mark.parametrize("chunk", range(4, CHUNKS))
+def test_fuzz_attention_corpus(chunk):
+    run_corpus_chunk("attn", chunk)

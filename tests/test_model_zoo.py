@@ -17,7 +17,6 @@ from repro.core import kernels_suite as ks
 from repro.core.backends.pallas_backend import PallasBackend
 from repro.core.backends.portable_math import (EXP_MAX_INPUT, EXP_MIN_INPUT,
                                                exp_jnp, exp_np)
-from repro.core.cache import TranslationCache
 from repro.core.passes import OPT_MAX, REFUSAL_REASONS, refusal_category
 
 ZOO_NAMES = sorted(zoo.ZOO)
@@ -124,7 +123,9 @@ def test_zoo_block_stats_refusals_are_named():
     REFUSAL_REASONS vocabulary (satellite: no more free-form strings)."""
     for name in ZOO_NAMES:
         prog, _oracle, grid, block, args, _outs = _launch(name)
-        backend = PallasBackend(cache=TranslationCache())
+        # the process-wide cache: the conformance runs above already
+        # translated these kernels, and block_stats count executions
+        backend = PallasBackend()
         eng = Engine(prog, backend, grid, block, dict(args))
         assert eng.run()
         stats = backend.block_stats

@@ -64,7 +64,8 @@ def test_lock_is_exclusive_and_reentrant_across_keys(tmp_path):
     hold("other", "other", 0.0)
     # the same key must wait for the holder
     hold("k", "b", 0.0)
-    t1.join()
+    t1.join(timeout=60)
+    assert not t1.is_alive()
     assert order.index(("exit", "a")) < order.index(("enter", "b"))
     # lock files persist (never unlinked — see DiskStore.lock docstring)
     assert list(store.dir.glob("*.lock"))
@@ -76,7 +77,7 @@ def test_single_flight_translation_threads(tmp_path):
     store = DiskStore(tmp_path, tag="t")
     caches = [TranslationCache(store=store) for _ in range(2)]
     key = ("mpstress", "shared-key")
-    started = threading.Barrier(2)
+    started = threading.Barrier(2, timeout=60)
     calls = []
 
     def translate():
@@ -92,7 +93,8 @@ def test_single_flight_translation_threads(tmp_path):
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        t.join(timeout=60)
+        assert not t.is_alive()
     assert len(calls) == 1
     assert sum(c.translated for c in caches) == 1
     assert sum(c.restored for c in caches) == 1
@@ -185,7 +187,8 @@ def test_gc_never_sweeps_a_held_lock(tmp_path):
     assert store.lock_sweeps == 0
     assert list(store.dir.glob("*.lock"))
     release.set()
-    t.join()
+    t.join(timeout=60)
+    assert not t.is_alive()
     store.gc()                          # now orphaned and free: swept
     assert store.lock_sweeps == 1
     assert not list(store.dir.glob("*.lock"))

@@ -10,6 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
+from repro.launch.mesh import auto_mesh
 from repro.configs.base import SHAPES, ParallelCfg, default_parallel
 from repro.models import registry as R
 from repro.parallel import MeshRules
@@ -18,7 +19,7 @@ from repro.parallel import MeshRules
 def test_param_specs_match_param_tree_structure():
     for arch in configs.list_archs():
         cfg = configs.get_smoke_config(arch)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         rules = MeshRules(cfg, ParallelCfg(), mesh)
         params = R.abstract_params(cfg)
         specs = rules.param_specs()
@@ -30,7 +31,7 @@ def test_param_specs_match_param_tree_structure():
 
 def test_divisibility_fallback_never_invalid():
     """Every spec entry must divide its dim by the mesh axis product."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     # simulate production sizes through the axis-size table
     for arch in configs.list_archs():
         cfg = configs.get_config(arch)
@@ -64,11 +65,12 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
+from repro.launch.mesh import auto_mesh
 from repro.configs.base import ParallelCfg
 from repro.models import layers as L
 from repro.parallel import MeshRules
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 cfg = configs.get_smoke_config("mixtral-8x22b")
 # 4 experts over the 4-wide model axis -> TRUE expert parallelism
 cfg = dataclasses.replace(cfg, moe_impl="grouped")

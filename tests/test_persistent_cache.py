@@ -274,7 +274,8 @@ def test_concurrent_writers_threads(tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=120)
+        assert not t.is_alive()
     assert not errors
     assert not list(store.dir.glob("*.tmp")), "leaked temp files"
     fresh = TranslationCache(store=DiskStore(tmp_path))

@@ -14,7 +14,7 @@ than as a handful of happy paths:
 * **durability round-trips bit-exactly**: ndarray args come back from the
   JSON tier with identical bytes, dtype, and shape.
 
-Runs in two modes, like ``test_fuzz_differential.py``: a fixed-seed
+Runs in two modes, like the fuzz harness (``fuzz_corpus.py``): a fixed-seed
 random corpus (no third-party dependency, deterministic) always runs;
 when hypothesis is installed the same simulation becomes a shrinking
 property test (``derandomize=True`` keeps CI reproducible).

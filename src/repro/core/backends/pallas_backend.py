@@ -47,6 +47,7 @@ from .. import hetir as ir
 from ..cache import TranslationCache
 from ..passes import block_lower, choose_block, refusal_category
 from ..segments import LoopStart, SegNode, segment_program
+from ..tracing import span
 from .base import (Backend, HostState, Launch, export_translation,
                    scalar_signature, state_signature)
 from .pallas_mem import (LANES, VMEM_BUDGET, BufLayout, PallasRefusal,
@@ -254,6 +255,7 @@ def emit_segment(seg: SegNode, launch: Launch, reg_sig: Tuple,
         out_shape=out_shapes,
         input_output_aliases=aliases,
         interpret=interpret,
+        name=f"{launch.program.name}.s{seg.index}",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )
@@ -358,9 +360,10 @@ class PallasBackend(Backend):
     # ------------------------------------------------------------------
     def run_segment(self, seg: SegNode, state: HostState,
                     launch: Launch) -> None:
-        reg_sig, glb_sig, shared_sig = state_signature(state)
-        call, meta = self._translate(seg, launch, reg_sig, glb_sig,
-                                     shared_sig)
+        with span("segment.lookup", seq=launch.launch_seq):
+            reg_sig, glb_sig, shared_sig = state_signature(state)
+            call, meta = self._translate(seg, launch, reg_sig, glb_sig,
+                                         shared_sig)
         if meta["block"] is not None:
             self.block_stats["tiled"] += 1
         else:
@@ -376,9 +379,14 @@ class PallasBackend(Backend):
 
         loops = np.array([np.asarray(state.regs[v]).reshape(-1)[0]
                           for v in meta["loop_vars"]] or [0], np.int32)
-        # globals stay device-resident between segments
-        for n in meta["glb_names"]:
-            state.globals_[n] = jnp.asarray(state.globals_[n])
+        # globals stay device-resident between segments: only those still
+        # on the host (a launch's first segment, or after a restore) move
+        host = [n for n in meta["glb_names"]
+                if isinstance(state.globals_[n], np.ndarray)]
+        if host:
+            with span("segment.upload", seq=launch.launch_seq):
+                for n in host:
+                    state.globals_[n] = jnp.asarray(state.globals_[n])
         args = [loops] + [state.regs[n] for n in meta["reg_io"]]
         if meta["has_shared"]:
             args.append(state.shared)

@@ -23,6 +23,7 @@ from __future__ import annotations
 import jax
 
 from ..segments import SegNode
+from ..tracing import span
 from .base import (Backend, HostState, Launch, export_translation,
                    scalar_signature, state_signature)
 from .semantics import Env, eval_stmts
@@ -64,7 +65,8 @@ class VectorizedBackend(Backend):
 
     def run_segment(self, seg: SegNode, state: HostState,
                     launch: Launch) -> None:
-        run = self._translate(seg, launch, state)
+        with span("segment.lookup", seq=launch.launch_seq):
+            run = self._translate(seg, launch, state)
         regs, shared, glbs = run(state.regs, state.shared, state.globals_)
         # keep state on-device between segments (registers are only pulled
         # to host numpy at snapshot time — Engine.snapshot)

@@ -72,6 +72,8 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Dict, Hashable, Iterator, Optional, Tuple
 
+from .tracing import timed
+
 try:
     import fcntl
 except ImportError:  # pragma: no cover - non-POSIX platform
@@ -709,13 +711,13 @@ class TranslationCache:
     def _revive(self, key: Hashable, env: Dict[str, Any]) -> Optional[Any]:
         """Run the reviver for one loaded envelope, account the restore,
         and insert the live value into the memory tier."""
-        t0 = time.perf_counter()
         _pop_restore_detail()  # drop any stale fields from a failed revive
-        try:
-            value = _REVIVERS[env["kind"]](env["payload"])
-        except Exception:
-            return None  # revival failure degrades to a miss
-        dt = (time.perf_counter() - t0) * 1e3
+        with timed("cache.revive") as sp:
+            try:
+                value = _REVIVERS[env["kind"]](env["payload"])
+            except Exception:
+                return None  # revival failure degrades to a miss
+        dt = sp.ms
         detail = _pop_restore_detail()
         if value is not None:
             with self._lock:
@@ -776,9 +778,9 @@ class TranslationCache:
             self, key: Hashable,
             translate: Callable[[], Tuple[Any, Optional[Tuple[str, Any]]]]
     ) -> Any:
-        t0 = time.perf_counter()
-        value, persist = translate()
-        dt = (time.perf_counter() - t0) * 1e3
+        with timed("cache.translate") as sp:
+            value, persist = translate()
+        dt = sp.ms
         with self._lock:
             self.translated += 1
             self.translate_ms += dt

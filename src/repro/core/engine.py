@@ -40,6 +40,7 @@ from .passes import (DEFAULT_OPT_LEVEL, OPT_MAX, SPECIALIZATION_POLICY,
 from .segments import (LoopEnd, LoopStart, Node, SegNode, dynamic_op_count,
                        resolve_trip_count, segment_program)
 from .state import Snapshot
+from .tracing import span
 
 
 class Engine:
@@ -176,7 +177,8 @@ class Engine:
                 return False
             node = self.nodes[self.node_idx]
             if isinstance(node, SegNode):
-                self.backend.run_segment(node, self.state, self.launch)
+                with span("segment", seq=self.launch.launch_seq):
+                    self.backend.run_segment(node, self.state, self.launch)
                 sched = self._node_sched.get(self.node_idx)
                 if sched is None:
                     sched = dynamic_op_count(node.stmts,

@@ -81,6 +81,7 @@ from .engine import Engine
 from .passes import DEFAULT_OPT_LEVEL, OPT_MAX
 from .pool import BufferPool
 from .state import Snapshot
+from .tracing import span, timed
 
 #: default ``sched_trace`` ring capacity — generous enough that every
 #: existing test window fits whole, small enough that a serving session
@@ -583,6 +584,8 @@ class LaunchRecord:
         self.bindings = dict(bindings)
         self.stream = stream
         self.seq = next(session._seq)
+        # when the launch was enqueued: its queue wait ends at the bind
+        self._queued_at = time.perf_counter()
         self._engine = engine
         self.finished = bool(engine is not None and engine.finished)
         self.cancelled = False
@@ -633,15 +636,16 @@ class LaunchRecord:
                     f"parameter {pname!r} ({db.uid}) was freed before the "
                     "launch reached the stream front — device memory must "
                     "stay alive until queued work that binds it has run")
-        eng = Engine(self.function.program, s.backend, self.grid,
-                     self.block, self._eng_args, opt_level=s.opt_level,
-                     specialize=s.specialize)
+        with span("launch.bind", seq=self.seq,
+                  queued_ms=(time.perf_counter() - self._queued_at) * 1e3):
+            eng = Engine(self.function.program, s.backend, self.grid,
+                         self.block, self._eng_args, opt_level=s.opt_level,
+                         specialize=s.specialize)
         eng.launch.stream_id = self.stream.sid
         eng.launch.launch_seq = self.seq
         self._engine = eng
         self._eng_args = None
         s.stats["last_opt"] = eng.opt_stats.as_dict()
-        s.stats["last_spec_key"] = eng.spec_key
         if eng.spec_key:
             s.stats["specialized_launches"] = \
                 s.stats.get("specialized_launches", 0) + 1
@@ -726,15 +730,16 @@ class LaunchRecord:
         buffer whose dtype differs from the kernel param's falls back to
         the old rebind-the-array semantics."""
         self.finished = True
-        for name, db in self.bindings.items():
-            if db.freed:
-                continue
-            res = np.asarray(self._engine.result(name))
-            if res.dtype == db.data.dtype:
-                np.copyto(db.data, res)
-            else:
-                db.data = res.copy()
-                db.dtype = ir.ir_dtype(res.dtype)
+        with span("launch.writeback", seq=self.seq):
+            for name, db in self.bindings.items():
+                if db.freed:
+                    continue
+                res = np.asarray(self._engine.result(name))
+                if res.dtype == db.data.dtype:
+                    np.copyto(db.data, res)
+                else:
+                    db.data = res.copy()
+                    db.dtype = ir.ir_dtype(res.dtype)
 
     def __repr__(self) -> str:
         state = ("finished" if self.finished else
@@ -1211,6 +1216,12 @@ class HetSession:
         if st is None:
             return False
         item = st._q[0]
+        with span("sched.step", seq=item.seq):
+            return self._serve(st, item)
+
+    def _serve(self, st: Stream, item) -> bool:
+        """Advance the front ``item`` of the picked stream ``st`` by one
+        scheduling decision."""
         if isinstance(item, CopyRecord):
             # data movement is scheduled work: one decision, one copy
             try:
@@ -1362,7 +1373,18 @@ class HetSession:
         """Serialize a launch paused at a barrier (or finished) — the
         migration payload.  Works on in-flight async launches: between
         scheduler steps every launch sits at a barrier by construction."""
-        return rec.engine.snapshot().to_bytes()
+        return self._checkpoint(rec)[0]
+
+    def _checkpoint(self, rec: LaunchRecord) -> Tuple[bytes, float]:
+        """:meth:`checkpoint`, and the milliseconds it took."""
+        with timed("checkpoint", seq=rec.seq) as sp:
+            with span("checkpoint.snapshot", seq=rec.seq):
+                snap = rec.engine.snapshot()
+            with span("checkpoint.encode", seq=rec.seq):
+                blob = snap.to_bytes()
+            # releasing the snapshot's copies is part of the checkpoint
+            del snap
+        return blob, sp.ms
 
     def restore(self, kernel: Union[str, Function], blob: bytes,
                 stream: Optional[Union[Stream, int]] = None
@@ -1376,47 +1398,63 @@ class HetSession:
         session lands results in the *same* buffer objects.  Unknown uids
         get fresh buffers that *adopt* the snapshot uid, so identity stays
         stable across chained migrations."""
-        snap = Snapshot.from_bytes(blob)
-        fn = kernel if isinstance(kernel, Function) \
-            else self.function(kernel)
-        eng = Engine.resume(fn.program, self.backend, snap)
-        history_key: Optional[int] = None
-        if isinstance(stream, (int, np.integer)):
-            # legacy int stream: the history view below must use the
-            # caller's id, which need not equal the Stream's internal sid
-            history_key = int(stream)
-            st = self._legacy_stream(history_key)
-        elif stream is None:
-            st = self.default_stream
-        elif stream.session is not self:
-            raise ValueError("stream belongs to a different session")
-        else:
-            st = stream
-        bindings: Dict[str, DeviceBuffer] = {}
-        for name, arr in eng.state.globals_.items():
-            arr_np = np.asarray(arr)
-            uid = eng.buffer_uids.get(name)
-            db = self._buffers_by_uid.get(uid) if uid else None
-            if db is not None and (db.size != arr_np.size
-                                   or db.np_dtype != arr_np.dtype
-                                   or db.freed):
-                db = None
-            if db is None:
-                db = DeviceBuffer(self, arr_np.size, arr_np.dtype, uid=uid)
-                self._buffers_by_uid[db.uid] = db
-            # seed with checkpoint contents so host reads before
-            # completion observe the paused state
-            np.copyto(db.data, arr_np)
-            bindings[name] = db
-        rec = LaunchRecord(self, fn, snap.num_blocks, snap.block_size,
-                           None, bindings, st, engine=eng)
-        if eng.finished:
-            rec._finish()
-        else:
-            st._enqueue(rec)
-        self._streams.setdefault(
-            st.sid if history_key is None else history_key, []).append(rec)
-        return rec
+        return self._restore(kernel, blob, stream)[0]
+
+    def _restore(self, kernel: Union[str, Function], blob: bytes,
+                 stream: Optional[Union[Stream, int]]
+                 ) -> Tuple[LaunchRecord, float]:
+        """:meth:`restore`, and the milliseconds it took.  The restored
+        launch's number (``seq``) exists only once its record does, so
+        the decode and resume spans carry none."""
+        with timed("restore") as sp:
+            with span("restore.decode"):
+                snap = Snapshot.from_bytes(blob)
+            fn = kernel if isinstance(kernel, Function) \
+                else self.function(kernel)
+            with span("restore.resume"):
+                eng = Engine.resume(fn.program, self.backend, snap)
+            history_key: Optional[int] = None
+            if isinstance(stream, (int, np.integer)):
+                # legacy int stream: the history view below must use the
+                # caller's id, which need not equal the Stream's sid
+                history_key = int(stream)
+                st = self._legacy_stream(history_key)
+            elif stream is None:
+                st = self.default_stream
+            elif stream.session is not self:
+                raise ValueError("stream belongs to a different session")
+            else:
+                st = stream
+            rec = LaunchRecord(self, fn, snap.num_blocks, snap.block_size,
+                               None, {}, st, engine=eng)
+            sp.set_metadata(seq=rec.seq)
+            with span("restore.rebind", seq=rec.seq):
+                for name, arr in eng.state.globals_.items():
+                    arr_np = np.asarray(arr)
+                    uid = eng.buffer_uids.get(name)
+                    db = self._buffers_by_uid.get(uid) if uid else None
+                    if db is not None and (db.size != arr_np.size
+                                           or db.np_dtype != arr_np.dtype
+                                           or db.freed):
+                        db = None
+                    if db is None:
+                        db = DeviceBuffer(self, arr_np.size, arr_np.dtype,
+                                          uid=uid)
+                        self._buffers_by_uid[db.uid] = db
+                    # seed with checkpoint contents so host reads before
+                    # completion observe the paused state
+                    np.copyto(db.data, arr_np)
+                    rec.bindings[name] = db
+            if eng.finished:
+                rec._finish()
+            else:
+                st._enqueue(rec)
+            self._streams.setdefault(
+                st.sid if history_key is None else history_key,
+                []).append(rec)
+            # releasing the decoded snapshot is part of the restore
+            del snap
+        return rec, sp.ms
 
     def run_to_completion(self, rec: LaunchRecord) -> None:
         """Drive the scheduler until ``rec`` finishes (equivalent to
@@ -1583,35 +1621,34 @@ def migrate(rec: LaunchRecord, src: HetSession, dst: HetSession,
     restores bit-identical, against warm specialized translations.
     DeviceBuffer uids ride along too: the destination's restored buffers
     adopt them, keeping buffer identity stable across chained hops."""
-    t0 = time.perf_counter()
-    blob = src.checkpoint(rec)  # capture at barrier
+    blob, checkpoint_ms = src._checkpoint(rec)  # capture at barrier
     rec.cancel()
-    t1 = time.perf_counter()
-    # warm the destination from the persistent tier: the engine's program
-    # is the *optimized* body, whose fingerprint is what cache keys carry
-    fp = ir.program_fingerprint(rec.engine.program)
-    restored = 0
-    if dst.cache.store is not None or dst.cache.shared is not None:
-        # the destination's own fabric: local store, then shared tier
-        restored = dst.cache.preload(backend=dst.backend_name,
-                                     fingerprint=fp)
-    else:
-        # fabric-less destination: fetch from the source's fabric, else
-        # fall back to the old point-to-point store handover
-        store = src.cache.shared if src.cache.shared is not None \
-            else src.cache.store
-        if store is not None:
+    with timed("migrate.preload", seq=rec.seq) as preload:
+        # warm the destination from the persistent tier: the engine's
+        # program is the *optimized* body, whose fingerprint is what cache
+        # keys carry
+        fp = ir.program_fingerprint(rec.engine.program)
+        restored = 0
+        if dst.cache.store is not None or dst.cache.shared is not None:
+            # the destination's own fabric: local store, then shared tier
             restored = dst.cache.preload(backend=dst.backend_name,
-                                         fingerprint=fp, store=store)
-    t2 = time.perf_counter()
-    new = dst.restore(kernel, blob, stream=stream)  # reload + reshard
-    t3 = time.perf_counter()
+                                         fingerprint=fp)
+        else:
+            # fabric-less destination: fetch from the source's fabric,
+            # else fall back to the old point-to-point store handover
+            store = src.cache.shared if src.cache.shared is not None \
+                else src.cache.store
+            if store is not None:
+                restored = dst.cache.preload(backend=dst.backend_name,
+                                             fingerprint=fp, store=store)
+    # reload + reshard
+    new, restore_ms = dst._restore(kernel, blob, stream=stream)
     src.stats["migrations"] += 1
     dst.stats["migrations"] += 1
     dst.stats["last_migration"] = {
-        "checkpoint_ms": (t1 - t0) * 1e3,
-        "warmup_ms": (t2 - t1) * 1e3,
-        "restore_ms": (t3 - t2) * 1e3,
+        "checkpoint_ms": checkpoint_ms,
+        "warmup_ms": preload.ms,
+        "restore_ms": restore_ms,
         "payload_bytes": len(blob),
         "cache_restored": restored,
     }

@@ -38,6 +38,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
 from .runtime import Function, HetSession, LaunchRecord, Stream
+from .tracing import span
 
 
 class QuotaExceeded(RuntimeError):
@@ -195,33 +196,35 @@ class ServingFrontEnd:
         in-flight cap.  In fleet mode ``fn`` may be the kernel name (the
         fleet registry resolves it in each worker) and ``args`` are host
         values (scalars / numpy arrays), not device buffers."""
-        t = self.tenants.get(name)
-        if t is None:
-            raise KeyError(f"unknown tenant {name!r} — register with "
-                           "front.tenant(name, ...) first")
-        self._reap(t)
-        if len(t.inflight) >= t.max_inflight:
-            t.rejected += 1
-            raise QuotaExceeded(
-                f"tenant {name!r} is at its in-flight quota "
-                f"({t.max_inflight}) — shed or retry after completions",
-                tenant=name)
-        if self.total_inflight >= self.max_inflight:
-            t.rejected += 1
-            raise QuotaExceeded(
-                f"serving front end is at its global in-flight cap "
-                f"({self.max_inflight}) — shed or retry after completions",
-                tenant=name)
-        if self.fleet is not None:
-            kernel = fn if isinstance(fn, str) else fn.name
-            rec = self.fleet.submit(kernel, grid, block, args)
-        else:
-            rec = fn.launch_async(grid, block, args, stream=t.stream)
-        ticket = ServeTicket(name, rec)
-        t.inflight.append(ticket)
-        t.admitted += 1
-        self.total_inflight += 1
-        return ticket
+        with span("serve.submit"):
+            t = self.tenants.get(name)
+            if t is None:
+                raise KeyError(f"unknown tenant {name!r} — register with "
+                               "front.tenant(name, ...) first")
+            self._reap(t)
+            if len(t.inflight) >= t.max_inflight:
+                t.rejected += 1
+                raise QuotaExceeded(
+                    f"tenant {name!r} is at its in-flight quota "
+                    f"({t.max_inflight}) — shed or retry after completions",
+                    tenant=name)
+            if self.total_inflight >= self.max_inflight:
+                t.rejected += 1
+                raise QuotaExceeded(
+                    f"serving front end is at its global in-flight cap "
+                    f"({self.max_inflight}) — shed or retry after "
+                    "completions",
+                    tenant=name)
+            if self.fleet is not None:
+                kernel = fn if isinstance(fn, str) else fn.name
+                rec = self.fleet.submit(kernel, grid, block, args)
+            else:
+                rec = fn.launch_async(grid, block, args, stream=t.stream)
+            ticket = ServeTicket(name, rec)
+            t.inflight.append(ticket)
+            t.admitted += 1
+            self.total_inflight += 1
+            return ticket
 
     # -- driving the scheduler ---------------------------------------------
     def pump(self, decisions: int = 64) -> bool:
@@ -229,12 +232,13 @@ class ServingFrontEnd:
         completions.  Returns True iff any progress was made.  In fleet
         mode a "decision" is one fleet pump round (dispatch sweep + one
         segment slice per busy worker)."""
-        if self.fleet is not None:
-            progressed = self.fleet.pump()
-        else:
-            progressed = self.session.step(decisions)
-        for t in self.tenants.values():
-            self._reap(t)
+        with span("serve.pump"):
+            if self.fleet is not None:
+                progressed = self.fleet.pump()
+            else:
+                progressed = self.session.step(decisions)
+            for t in self.tenants.values():
+                self._reap(t)
         return progressed
 
     def drain(self, timeout: Optional[float] = None) -> bool:

@@ -111,6 +111,30 @@ def test_attn_decode_compiles_at_llama_decode_width(one_chip):
     assert windows == {"K": 128 * 128, "V": 128 * 128}
 
 
+def test_segment_kernel_is_named_for_its_segment(one_chip):
+    """A segment kernel compiles to a custom call named
+    ``<program>.s<index>``, which is how a device trace names the
+    segment that ran."""
+    import re
+
+    import jax
+
+    prog, _ = zoo.attn_decode(D=128, T=128)
+    grid, block, args = _attn_width_args()
+    seg, launch, reg_sig, glb_sig, shared_sig = _signatures(
+        prog, grid, block, args)[2]
+    fn, _, example = pb.emit_segment(seg, launch, reg_sig, glb_sig,
+                                     shared_sig, choose_block(grid * block),
+                                     interpret=False)
+    structs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+               for s in example]
+    text = jax.jit(fn).lower(*structs).compile().as_text()
+    calls = re.findall(r"^\s*%([\w.\-]+) = .*? custom-call\(", text,
+                       re.MULTILINE)
+    assert [re.sub(r"\.\d+$", "", c) for c in calls] == \
+        [f"attn_decode.s{seg.index}"]
+
+
 def test_vadd_block_tiled_compiles_at_16k(one_chip):
     prog, _ = suite.vadd()
     n = 16384

@@ -29,8 +29,9 @@ registers" snapshot-size optimization.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from . import hetir as ir
@@ -59,6 +60,11 @@ class Engine:
         # every scalar is known) may bind them into the optimized body
         scalars: Dict[str, object] = {}
         shapes: Dict[str, tuple] = {}
+        # DeviceBuffer identity (runtime.py object model): param name ->
+        # the uid of the buffer handle bound at launch (raw host arrays
+        # have none).  Rides in every snapshot so restore/migration can
+        # re-bind the same live buffer — identity survives checkpoints.
+        self.buffer_uids: Dict[str, str] = {}
         if not _from_snapshot:
             for p in program.scalars():
                 if p.name not in args:
@@ -71,7 +77,8 @@ class Engine:
             for p in program.buffers():
                 if p.name in args:
                     val = args[p.name]
-                    if hasattr(val, "uid") and hasattr(val, "data"):
+                    if _is_handle(val):
+                        self.buffer_uids[p.name] = val.uid
                         val = val.data
                     shapes[p.name] = tuple(np.shape(val))
         # run the pass pipeline before translation (paper §4.2: the runtime
@@ -116,11 +123,12 @@ class Engine:
         # for an engine, and segment-level loops re-execute their nodes.
         self.executed_ops = 0
         self._node_sched: Dict[int, int] = {}
-        # DeviceBuffer identity (runtime.py object model): param name ->
-        # the uid of the buffer handle bound at launch (None for raw host
-        # arrays).  Rides in every snapshot so restore/migration can
-        # re-bind the same live buffer — identity survives checkpoints.
-        self.buffer_uids: Dict[str, Optional[str]] = {}
+        # read-only DeviceBuffers bound by reference: param name -> (the
+        # handle, its host array); the handle detaches this engine before
+        # any in-place host write (copy-on-write, see detach)
+        self._by_ref: Dict[str, Tuple[object, np.ndarray]] = {}
+        #: bytes the bind copied (the buffers the launch writes)
+        self.copied_bytes = 0
 
         # registers that any segment reads — everything else is dead between
         # segments and gets pruned from state (the paper's "only saving live
@@ -132,6 +140,9 @@ class Engine:
             elif isinstance(n, LoopStart):
                 self._live.add(n.var.name)
 
+        #: buffer parameters the launch writes (resume recomputes it
+        #: from the snapshot's uids)
+        self.written = self._written_params()
         if _from_snapshot:
             return
 
@@ -141,14 +152,24 @@ class Engine:
                 raise ValueError(f"missing buffer argument {p.name}")
             val = args[p.name]
             # a runtime.DeviceBuffer handle (duck-typed — runtime imports
-            # this module, not the reverse): unwrap and record its uid
-            if hasattr(val, "uid") and hasattr(val, "data"):
-                self.buffer_uids[p.name] = val.uid
-                val = val.data
+            # this module, not the reverse): unwrap it
+            handle = val if _is_handle(val) else None
+            if handle is not None:
+                val = handle.data
             buf = np.asarray(val, dtype=ir.np_dtype(p.dtype))
             if buf.ndim != 1:
                 raise ValueError(f"buffer {p.name} must be 1-D")
-            globals_[p.name] = buf.copy()
+            readers = getattr(handle, "_readers", None)
+            if readers is not None and buf is handle.data \
+                    and p.name not in self.written:
+                # the launch never writes this buffer: read it in place
+                readers.add(self)
+                self._by_ref[p.name] = (handle, buf)
+                globals_[p.name] = buf
+            else:
+                # raw host arrays keep copy-in semantics
+                globals_[p.name] = buf.copy()
+                self.copied_bytes += buf.nbytes
 
         shared = None
         if program.shared_size:
@@ -291,6 +312,7 @@ class Engine:
         eng.launch.buffer_shapes = {k: tuple(np.shape(v))
                                     for k, v in snap.globals_.items()}
         eng.buffer_uids = dict(snap.buffer_uids)
+        eng.written = eng._written_params()
         eng.node_idx = snap.node_idx
         eng.loop_counters = dict(snap.loop_counters)
         eng.state = HostState(
@@ -304,3 +326,59 @@ class Engine:
     # ------------------------------------------------------------------
     def result(self, buf: str) -> np.ndarray:
         return np.asarray(self.state.globals_[buf])
+
+    # -- read-only buffers bound by reference ----------------------------
+    def _written_params(self) -> frozenset:
+        """The buffer parameters this launch writes: those a STORE or
+        ATOMIC of some segment targets, and every parameter bound to the
+        same DeviceBuffer as one of them."""
+        direct: set = set()
+        for n in self.nodes:
+            if isinstance(n, SegNode):
+                direct |= n.gwrites
+        uids = {self.buffer_uids.get(n) for n in direct} - {None}
+        return frozenset(direct | {n for n, u in self.buffer_uids.items()
+                                   if u in uids})
+
+    def detach(self, handle) -> bool:
+        """Give this engine a private copy of what it reads of ``handle``
+        by reference — called before the host writes the buffer in place,
+        so the launch keeps seeing the buffer as it was at bind.  Returns
+        True iff a copy was made: a buffer already uploaded to an
+        accelerator's memory needs none."""
+        names = [n for n, (h, _) in self._by_ref.items() if h is handle]
+        if not names:
+            return False
+        # an upload of the host array, or a segment it feeds, may still be
+        # running and reading it
+        jax.block_until_ready(
+            (self.state.regs, self.state.shared, self.state.globals_))
+        private = None
+        for name in names:
+            _, host = self._by_ref.pop(name)
+            if _reads_host(self.state.globals_[name], host):
+                if private is None:
+                    private = host.copy()
+                self.state.globals_[name] = private
+        return private is not None
+
+    def release(self) -> None:
+        """Drop every by-reference hold (the launch finished, was
+        cancelled or migrated away): later host writes copy nothing."""
+        for h, _ in self._by_ref.values():
+            h._readers.discard(self)
+        self._by_ref.clear()
+
+
+def _is_handle(val) -> bool:
+    return hasattr(val, "uid") and hasattr(val, "data")
+
+
+def _reads_host(value, host: np.ndarray) -> bool:
+    """Whether an engine's global ``value`` may read the memory of the
+    host array ``host``: a view of it, or an array on a CPU device, which
+    may be a zero-copy view of it or read it lazily.  An array in an
+    accelerator's memory is a finished copy."""
+    if isinstance(value, np.ndarray):
+        return np.shares_memory(value, host)
+    return any(d.platform == "cpu" for d in value.devices())

@@ -175,12 +175,24 @@ class DeviceBuffer:
     variants enqueue the transfer on a :class:`Stream` instead, so it
     runs in stream order and participates in scheduling.
 
+    Binding contract: a launch sees its buffers as they were when it
+    bound them.  A buffer the launch writes (a STORE or ATOMIC on any
+    parameter bound to it) is copied at bind and written back at finish;
+    a buffer it only reads is bound **by reference**, with no copy and no
+    write-back.  While a launch holds ``data`` by reference, every
+    in-place host write through this handle (``copy_from_host``, an
+    async H2D copy, ``fill``, another launch's write-back, a restore's
+    re-bind, ``free``) first gives that launch a private copy of the old
+    contents (``stats["bind_cow_copies"]`` counts them).  Writes into
+    ``data`` itself, bypassing the handle, are not intercepted.
+
     Backings come from the session's :class:`~repro.core.pool.BufferPool`
     when one is attached: ``data`` is a ``size``-element view of a
     size-class backing, and :meth:`free` returns the backing for reuse.
     """
 
-    __slots__ = ("session", "uid", "dtype", "data", "freed", "_backing")
+    __slots__ = ("session", "uid", "dtype", "data", "freed", "_backing",
+                 "_readers")
 
     def __init__(self, session: "HetSession", size: int,
                  dtype: object = np.float32, uid: Optional[str] = None,
@@ -204,6 +216,8 @@ class DeviceBuffer:
             self._backing = None
             self.data = np.zeros(size, dtype=np_dt)
         self.freed = False
+        # the in-flight launches' engines that read ``data`` by reference
+        self._readers: set = set()
 
     # -- geometry ----------------------------------------------------------
     @property
@@ -228,7 +242,7 @@ class DeviceBuffer:
             raise ValueError(
                 f"host array has {arr.size} elements, buffer holds "
                 f"{self.size}")
-        np.copyto(self.data, arr.reshape(-1), casting="same_kind")
+        self._write(arr.reshape(-1))
         return self
 
     def copy_to_host(self) -> np.ndarray:
@@ -266,6 +280,7 @@ class DeviceBuffer:
 
     def fill(self, value) -> "DeviceBuffer":
         self._check_alive()
+        self._detach_readers()
         self.data.fill(value)
         return self
 
@@ -277,6 +292,8 @@ class DeviceBuffer:
         buffer pool for reuse.  Idempotent."""
         if self.freed:
             return
+        # the pool may hand the backing to a new buffer
+        self._detach_readers()
         self.session._buffers_by_uid.pop(self.uid, None)
         self.freed = True
         backing, self._backing = self._backing, None
@@ -286,6 +303,21 @@ class DeviceBuffer:
     def _check_alive(self) -> None:
         if self.freed:
             raise ValueError(f"buffer {self.uid} has been freed")
+
+    def _write(self, arr: np.ndarray) -> None:
+        """The host's in-place write of ``data``."""
+        self._detach_readers()
+        np.copyto(self.data, arr, casting="same_kind")
+
+    def _detach_readers(self) -> None:
+        """Copy-on-write, before ``data`` is written in place: each
+        launch that reads it by reference gets a private copy first."""
+        if not self._readers:
+            return
+        readers, self._readers = self._readers, set()
+        for eng in readers:
+            if eng.detach(self):
+                self.session.stats["bind_cow_copies"] += 1
 
     def __repr__(self) -> str:
         state = "freed" if self.freed else f"{self.size}x{self.dtype}"
@@ -398,7 +430,7 @@ class CopyRecord:
                 "copy reached the stream front — device memory must stay "
                 "alive until queued work that binds it has run")
         if self.kind == "h2d":
-            np.copyto(db.data, self._host.reshape(-1), casting="same_kind")
+            db._write(self._host.reshape(-1))
             self._host = None
         else:
             self._array = db.data.copy()
@@ -564,8 +596,8 @@ class LaunchRecord:
     """Future for an enqueued kernel launch.
 
     A record enqueued via :meth:`Function.launch_async` is *lazy*: its
-    :class:`~repro.core.engine.Engine` (which snapshots buffer contents
-    and translates) materializes only when the launch reaches the front of
+    :class:`~repro.core.engine.Engine` (which binds the buffers and
+    translates) materializes only when the launch reaches the front of
     its stream with all prior work done — that is what gives same-stream
     dataflow CUDA semantics.  Accessing ``.engine`` earlier forces
     materialization (used by ``checkpoint`` of a not-yet-started launch).
@@ -637,10 +669,12 @@ class LaunchRecord:
                     "launch reached the stream front — device memory must "
                     "stay alive until queued work that binds it has run")
         with span("launch.bind", seq=self.seq,
-                  queued_ms=(time.perf_counter() - self._queued_at) * 1e3):
+                  queued_ms=(time.perf_counter() - self._queued_at) * 1e3
+                  ) as sp:
             eng = Engine(self.function.program, s.backend, self.grid,
                          self.block, self._eng_args, opt_level=s.opt_level,
                          specialize=s.specialize)
+            sp.set_metadata(copied_bytes=eng.copied_bytes)
         eng.launch.stream_id = self.stream.sid
         eng.launch.launch_seq = self.seq
         self._engine = eng
@@ -722,21 +756,27 @@ class LaunchRecord:
         except ValueError:
             pass
         self.cancelled = True
+        if self._engine is not None:
+            self._engine.release()
 
     def _finish(self) -> None:
         """Completion hook: propagate kernel writes into the bound
-        DeviceBuffers *in place* (object identity preserved).  The typed
-        binding guarantees matching dtypes on the new surface; a legacy
-        buffer whose dtype differs from the kernel param's falls back to
-        the old rebind-the-array semantics."""
+        DeviceBuffers *in place* (object identity preserved).  Only the
+        buffers the launch writes are written back; a buffer it only
+        reads was bound by reference and keeps its contents and identity.
+        The typed binding guarantees matching dtypes on the new surface; a
+        legacy buffer whose dtype differs from the kernel param's falls
+        back to the old rebind-the-array semantics."""
         self.finished = True
+        eng = self._engine
+        eng.release()
         with span("launch.writeback", seq=self.seq):
             for name, db in self.bindings.items():
-                if db.freed:
+                if db.freed or name not in eng.written:
                     continue
-                res = np.asarray(self._engine.result(name))
+                res = np.asarray(eng.result(name))
                 if res.dtype == db.data.dtype:
-                    np.copyto(db.data, res)
+                    db._write(res)
                 else:
                     db.data = res.copy()
                     db.dtype = ir.ir_dtype(res.dtype)
@@ -1008,7 +1048,7 @@ class HetSession:
                       "translation_ms": 0.0,  # deprecated alias, see API.md
                       "segments_executed": 0, "migrations": 0,
                       "async_copies": 0, "sched_trace_dropped": 0,
-                      "streams_retired": 0,
+                      "streams_retired": 0, "bind_cow_copies": 0,
                       "cache_hits": 0, "cache_misses": 0,
                       "cache_evictions": 0, "cache_restored": 0,
                       "cache_translated": 0}
@@ -1443,7 +1483,7 @@ class HetSession:
                         self._buffers_by_uid[db.uid] = db
                     # seed with checkpoint contents so host reads before
                     # completion observe the paused state
-                    np.copyto(db.data, arr_np)
+                    db._write(arr_np)
                     rec.bindings[name] = db
             if eng.finished:
                 rec._finish()

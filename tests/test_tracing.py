@@ -21,9 +21,10 @@ H, D, T, NTILES = 2, 16, 32, 2
 SCENARIOS = ("launch", "serve", "migrate")
 
 #: every span of the runtime, and the counters it must carry: the
-#: launch's ``seq`` wherever one exists, and the bind's queue wait
+#: launch's ``seq`` wherever one exists, and the bind's queue wait and
+#: bytes copied
 SPANS = {
-    "hetgpu.launch.bind": {"seq", "queued_ms"},
+    "hetgpu.launch.bind": {"seq", "queued_ms", "copied_bytes"},
     "hetgpu.launch.writeback": {"seq"},
     "hetgpu.segment": {"seq"},
     "hetgpu.segment.lookup": {"seq"},
@@ -185,6 +186,16 @@ def test_bind_precedes_the_launch_first_segment(traced, scenario):
                     and e[3]["seq"] == st["seq"])
         assert bind_end <= first
         assert st["queued_ms"] >= 0
+
+
+@pytest.mark.parametrize("scenario", SCENARIOS)
+def test_bind_copies_only_the_written_buffer(traced, scenario):
+    """``attn_decode`` writes O alone: the bind copies its H*D floats, and
+    binds the read-only Q, K and V by reference."""
+    binds = [e[3] for e in traced["spans"][scenario]
+             if e[0] == "hetgpu.launch.bind"]
+    assert binds
+    assert all(st["copied_bytes"] == H * D * 4 for st in binds)
 
 
 @pytest.mark.parametrize("key,span", [

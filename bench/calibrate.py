@@ -37,10 +37,13 @@ def main(argv=None) -> int:
                                started, control=True)
         started = 0.0
         gc.collect()    # the last run's sessions hold reference cycles
+        limited = [name for name in res["compared"] if name in res["control"]]
         print(json.dumps({"seed": seed, "correct": res["correct"],
                           "attempted": res["attempted"],
-                          "o_err_max": res["compared"]["o_err_max"]["value"],
-                          "control_o_err_max": res["control"]["o_err_max"],
+                          **{name: res["compared"][name]["value"]
+                             for name in limited},
+                          **{f"control_{name}": res["control"][name]
+                             for name in limited},
                           "control_correct": res["control"]["correct"],
                           "metrics": res["metrics"]}), flush=True)
     return 0

@@ -1,11 +1,49 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the plain reference, and the result line.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric is found by name under ``bench/``: ``configs/<config>.json``,
-``traffic/<mix>.json``, ``metrics/<metric>.py``; the program side and the
-reference of a configuration's kernel are ``kernels/<kernel>.py`` and
-``reference/<kernel>.py``.
+Everything that belongs to one configuration, traffic mix, kernel, path
+or per-layer metric is found by name under ``bench/``:
+``configs/<config>.json``, ``traffic/<mix>.json``, ``metrics/<metric>.py``,
+``kernels/<kernel>.py`` and ``reference/<kernel>.py`` for the
+configuration's ``kernel``, and ``paths/<path>.py`` for a mix whose
+``path`` is not one of ``loadgen.PATHS``.  The harness and the paths know
+nothing of one kernel's shapes or buffers; a new kernel or path is new
+files only.
+
+The kernel module (the program side) provides:
+
+* ``shape(config)`` — the configuration's shape block, which the harness
+  and the paths hand back to the kernel and reference modules unread;
+* ``units(config)`` — how many resident units the run binds; each event
+  reads one of them;
+* ``program(shape)`` and ``geometry(shape)`` — the hetIR program and the
+  launch's ``(grid, block)``;
+* ``outputs(shape)`` — each event's output buffers, ``{name: (elements,
+  dtype)}``: the buffers the comparison reads;
+* ``args(shape, bufs)`` — the launch's arguments from its buffers by
+  name: the unit's, the shared ones, the event's inputs and outputs;
+* ``answer(shape, outs)`` — from one event's outputs on the host
+  (``{name: array}``), the answer in the form the reference's ``check``
+  reads;
+* ``rests(shape)`` (for the ``migrate`` path) — the segment counts at
+  which a launch may rest at a barrier; a kernel without them is refused
+  when the path plans.
+
+The reference module (independent of the program) provides:
+
+* ``draw(seed, shape, n_units, n_events)`` — every input of the run from
+  the seed: ``{"unit": {name: array with a leading unit axis}, "shared":
+  {name: array}, "event": {name: array with a leading event axis}}``;
+* ``check(answers, shape, seed, control=False)`` — ``{<limit name>:
+  worst, "compared": n}`` over the window's answers, ``(record, answer)``
+  pairs whose record names the unit (``seq``) and the drawn event inputs
+  (``q``) it used; with ``control`` the reference's lower-precision twin
+  is read in the program's place;
+* ``work(shape)`` — the algorithm's ``{"flops", "bytes"}`` for one
+  launch, from its shapes alone.
+
+``verdict`` holds every key of the configuration's ``limits`` against
+the check's value of that key, and every event of the window answered.
 """
 from __future__ import annotations
 
@@ -17,8 +55,6 @@ import time
 from pathlib import Path
 from types import SimpleNamespace
 from typing import Dict, List, Optional
-
-import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 OUT = BENCH.parent / ".bench_out"
@@ -98,40 +134,30 @@ def cache_counters() -> Dict[str, object]:
     return {k: st.get(k) for k in keep}
 
 
-def compare(answers, attn: Dict, ref, seed: int, control: bool = False
+def kernel_modules(config: Dict):
+    """The program side and the reference of the configuration's kernel:
+    ``kernels/<kernel>.py`` and ``reference/<kernel>.py``."""
+    name = config["kernel"]
+    return (load_module(BENCH / "kernels" / f"{name}.py", "bench_kernel"),
+            load_module(BENCH / "reference" / f"{name}.py", "bench_reference"))
+
+
+def compare(answers, shape: Dict, ref, seed: int, control: bool = False
             ) -> Dict[str, object]:
-    """Each answer's O against the reference's, grouped by resident
-    sequence so each sequence's keys and values are drawn once.  With
-    ``control`` the reference's lower-precision twin is compared in the
-    program's place, over the same queries."""
-    H, D = int(attn["heads"]), int(attn["head_dim"])
-    S, scale = int(attn["keys"]), float(attn["scale"])
-    by_seq: Dict[int, List] = {}
-    for rec, o in answers:
-        by_seq.setdefault(rec["seq"], []).append((rec, o))
-    worst, n = 0.0, 0
-    for s, items in sorted(by_seq.items()):
-        k, v = (np.asarray(x, np.float64)
-                for x in ref.sequence(seed, s, H, S, D))
-        qs = ref.queries(seed, [rec["q"] for rec, _ in items], H, D)
-        if control:
-            kc, vc = ref.split_high(k), ref.split_high(v)
-        for (rec, o), q in zip(items, qs):
-            want = ref.decode(q, k, v, scale)
-            got = ref.decode_control(q, kc, vc, scale) if control else o
-            worst = max(worst, ref.rel_err(got, want))
-            n += 1
-    return {"o_err_max": worst, "compared": n}
+    """The reference's check of the window's answers; with ``control``
+    the reference's lower-precision twin is read in the program's place,
+    over the same events."""
+    return ref.check(answers, shape, seed, control=control)
 
 
 def verdict(check: Dict, failed: int, attempted: int, limits: Dict):
     """``correct`` and the numbers compared, each with its limit: every
-    event of the window answered, each answer compared, and the worst
-    within the configuration's limit."""
-    compared = {"o_err_max": [check["o_err_max"], limits["o_err_max"]],
-                "unanswered": [failed, 0]}
-    correct = (check["o_err_max"] <= limits["o_err_max"] and failed == 0
-               and check["compared"] == attempted)
+    event of the window answered, each answer compared, and every number
+    the configuration limits within its limit."""
+    compared = {name: [check[name], limit] for name, limit in limits.items()}
+    compared["unanswered"] = [failed, 0]
+    correct = (all(check[name] <= limit for name, limit in limits.items())
+               and failed == 0 and check["compared"] == attempted)
     return bool(correct), compared
 
 
@@ -164,7 +190,7 @@ def run_cell(spec: Dict, workload: Dict, seed: int, seconds: float,
     benchmark's metric lists by name: ``end_to_end`` and ``per_layer``
     (name -> entry), and the names this cell reports of each.  With
     ``control`` (calibration only, never a benchmark run) the result also
-    carries the control's reading over the same queries."""
+    carries the control's reading over the same events."""
     import end_to_end
     import loadgen
     import trace_reduce
@@ -174,22 +200,17 @@ def run_cell(spec: Dict, workload: Dict, seed: int, seconds: float,
     device = require(int(workload["chips"]))
     config = load_json("configs", workload["config"])
     traffic = load_json("traffic", workload["traffic"])
-    attn = config["attn"]
-    kernel = load_module(BENCH / "kernels" / f"{config['kernel']}.py",
-                         "bench_kernel")
-    ref = load_module(BENCH / "reference" / f"{config['kernel']}.py",
-                      "bench_reference")
+    kernel, ref = kernel_modules(config)
+    shape = kernel.shape(config)
 
     path = loadgen.path_for(config, traffic, kernel, seed)
-    n_q = path.plan(seconds)
-    H, D = int(attn["heads"]), int(attn["head_dim"])
-    S = int(attn["keys"])
+    n_events = path.plan(seconds)
     marks = [("start", time.perf_counter() - t_start)]
-    k, v, q = ref.inputs(seed, H, S, D, path.n_seq, n_q)
+    inputs = ref.draw(seed, shape, path.n_units, n_events)
     marks.append(("inputs", time.perf_counter() - t_start))
     peak_inputs = memory_peak_bytes()
-    path.prepare(k, v, q)
-    del k, v, q
+    path.prepare(inputs)
+    del inputs
     marks.append(("prepare", time.perf_counter() - t_start))
     path.warm()
     setup_counters = cache_counters()
@@ -248,11 +269,11 @@ def run_cell(spec: Dict, workload: Dict, seed: int, seconds: float,
          "pumps_over_ms": getattr(path, "long_pumps", [])}, default=str))
 
     limits = config["limits"]
-    correct, compared = verdict(compare(answers, attn, ref, seed), failed,
+    correct, compared = verdict(compare(answers, shape, ref, seed), failed,
                                 attempted, limits)
     control_reading = None
     if control:
-        control_reading = compare(answers, attn, ref, seed, control=True)
+        control_reading = compare(answers, shape, ref, seed, control=True)
         control_reading["correct"] = verdict(control_reading, failed,
                                              attempted, limits)[0]
 
@@ -261,8 +282,7 @@ def run_cell(spec: Dict, workload: Dict, seed: int, seconds: float,
     ctx = SimpleNamespace(
         spec=spec, records=records, setup_counters=setup_counters, e2e=e2e,
         extra=extra, trace=None,
-        bound_s=work.bound_s(getattr(work, config["kernel"])(H, S, D),
-                             work.peaks(device["kind"])))
+        bound_s=work.bound_s(ref.work(shape), work.peaks(device["kind"])))
     if trace:
         ctx.trace = trace_reduce.reduce_trace(TRACE_DIR)
         log("trace: " + json.dumps(ctx.trace))

@@ -1,24 +1,27 @@
 """The one traffic generator.  A mix is a data file under ``traffic/``
 whose ``path`` names how its events reach the program, and whose other
-keys are that path's parameters:
+entries are that path's parameters:
 
 * ``launch`` — a closed loop of blocking ``Function.launch`` calls on one
-  ``HetSession``, each with a new query against the next resident
-  sequence (round robin), and its output copied to the host;
+  ``HetSession``, each with a new event's inputs against the next
+  resident unit (round robin), and its outputs copied to the host;
 * ``serve`` — an open loop through ``ServingFrontEnd``: requests arrive
   on a fixed trace of Gamma-distributed gaps (rate, coefficient of
   variation), each from a tenant drawn by popularity, and each is one
-  launch against one of its tenant's resident sequences.  Latency runs
-  from the request's *due* time to its output on the host;
+  launch against one of its tenant's resident units.  Latency runs from
+  the request's *due* time to its outputs on the host;
 * ``migrate`` — a closed loop of launches that start on one backend,
-  rest at a key-tile barrier, are moved with ``migrate()`` to a session
-  on another backend and finish there.  Downtime runs from the pause to
-  the target's first segment done.
+  rest at one of the kernel's rest points, are moved with ``migrate()``
+  to a session on another backend and finish there.  Downtime runs from
+  the pause to the target's first segment done.
 
-Every path warms up exactly the calls its window makes, records one dict
-per event of the window, and keeps each event's output for the
-comparison with the reference.  Spans named ``bench.<step>`` mark the
-host's steps in a profiler trace.
+A path not in :data:`PATHS` is the ``PATH`` class of ``paths/<path>.py``
+(:func:`path_for`).  Every path warms up exactly the calls its window
+makes, records one dict per event of the window, and keeps each event's
+answer (``kernel.answer``) for the comparison with the reference.  What a
+kernel's buffers are and how its launch is built is the kernel module's
+(``bench/harness.py`` lists its interface).  Spans named ``bench.<step>``
+mark the host's steps in a profiler trace.
 """
 from __future__ import annotations
 
@@ -96,17 +99,19 @@ def block_on(engine) -> None:
 
 class Path:
     """One way for events to reach the program.  ``plan`` fixes how many
-    distinct queries the run draws, ``prepare`` puts the inputs into the
-    program's buffers, ``warm`` makes one event of each kind the window
-    makes, and ``window`` runs for the given seconds."""
+    distinct events the run draws, ``prepare`` puts the drawn inputs into
+    the program's buffers, ``warm`` makes one event of each kind the
+    window makes, and ``window`` runs for the given seconds."""
 
     def __init__(self, config: Dict, traffic: Dict, kernel, seed: int):
         self.traffic, self.kernel = traffic, kernel
-        self.attn = config["attn"]
-        self.n_seq = int(config["resident_sequences"])
-        self.grid, self.block = kernel.geometry(self.attn)
+        self.kernel_name = config["kernel"]
+        self.shape = kernel.shape(config)
+        self.n_units = int(kernel.units(config))
+        self.grid, self.block = kernel.geometry(self.shape)
+        self.outputs = kernel.outputs(self.shape)
         self.rng = np.random.default_rng(seed)
-        self.answers: List[Tuple[Dict, np.ndarray]] = []
+        self.answers: List[Tuple[Dict, object]] = []
         self.sessions: Dict[str, object] = {}
         #: device bytes in use as each event of the window ends
         self.bytes_in_use: List[int] = []
@@ -121,23 +126,37 @@ class Path:
             self.sessions[backend] = HetSession(backend)
         return self.sessions[backend]
 
-    def resident(self, sess, k: np.ndarray, v: np.ndarray) -> None:
-        """Copy every resident sequence's keys and values into buffers of
-        ``sess`` (``self.kv``)."""
-        self.kv = []
-        for s in range(self.n_seq):
-            kb = sess.alloc(k[s].size).copy_from_host(k[s])
-            vb = sess.alloc(v[s].size).copy_from_host(v[s])
-            self.kv.append((kb, vb))
+    def resident(self, sess, inputs: Dict) -> None:
+        """Copy every resident unit's inputs and the shared ones into
+        buffers of ``sess``."""
+        self.unit_bufs = [
+            {name: sess.alloc(a[u].size, a.dtype).copy_from_host(a[u])
+             for name, a in inputs["unit"].items()}
+            for u in range(self.n_units)]
+        self.shared_bufs = {
+            name: sess.alloc(a.size, a.dtype).copy_from_host(a)
+            for name, a in inputs["shared"].items()}
 
-    def shape_o(self, o: np.ndarray) -> np.ndarray:
-        return np.asarray(o, np.float32).reshape(self.grid, -1)
+    def event_buffers(self, sess, event: Dict) -> Dict[str, object]:
+        """Empty buffers for one event's inputs, sized by drawn event 0."""
+        return {name: sess.alloc(a[0].size, a.dtype)
+                for name, a in event.items()}
+
+    def output_buffers(self, sess) -> Dict[str, object]:
+        return {name: sess.alloc(size, dtype)
+                for name, (size, dtype) in self.outputs.items()}
+
+    def args(self, u: int, ins: Dict, outs: Dict) -> Dict[str, object]:
+        """The launch's arguments for an event on resident unit ``u``."""
+        return self.kernel.args(self.shape, {**self.shared_bufs,
+                                             **self.unit_bufs[u], **ins,
+                                             **outs})
 
     def release(self) -> None:
         for sess in self.sessions.values():
             sess.synchronize()
         self.sessions.clear()
-        self.kv = []
+        self.unit_bufs, self.shared_bufs = [], {}
 
     def segments(self) -> int:
         """Segment kernels executed so far by the pallas sessions (the
@@ -147,25 +166,41 @@ class Path:
                               for sess in self.sessions.values()))
 
 
+def fill(bufs: Dict[str, object], event: Dict, j: int) -> Dict[str, object]:
+    """Copy drawn event ``j``'s inputs into ``bufs``."""
+    for name, b in bufs.items():
+        b.copy_from_host(event[name][j])
+    return bufs
+
+
+def to_host(bufs: Dict[str, object]) -> Dict[str, np.ndarray]:
+    return {name: b.copy_to_host() for name, b in bufs.items()}
+
+
 class LaunchPath(Path):
-    """Closed loop, one stream: launch, wait, output to the host."""
+    """Closed loop, one stream: launch, wait, outputs to the host."""
 
-    def prepare(self, k, v, q) -> None:
-        self.q = q
+    def prepare(self, inputs: Dict) -> None:
         sess = self.session(BACKEND)
-        self.fn = sess.load(self.kernel.program(self.attn)).function()
-        self.resident(sess, k, v)
-        self.qb = sess.alloc(q[0].size)
-        self.ob = sess.alloc(self.grid * int(self.attn["head_dim"]))
+        self.fn = sess.load(self.kernel.program(self.shape)).function()
+        self.bind(sess, inputs)
 
-    def _one(self, i: int, s: int) -> np.ndarray:
-        kb, vb = self.kv[s]
+    def bind(self, sess, inputs: Dict) -> None:
+        """The resident inputs into ``sess``, and one set of event buffers
+        that every launch fills anew."""
+        self.event_in = inputs["event"]
+        self.n_drawn = len(next(iter(self.event_in.values())))
+        self.resident(sess, inputs)
+        self.ins = self.event_buffers(sess, self.event_in)
+        self.outs = self.output_buffers(sess)
+
+    def _one(self, i: int, u: int) -> Dict[str, np.ndarray]:
         with span("launch"):
-            self.qb.copy_from_host(self.q[i % len(self.q)])
-            self.fn.launch(self.grid, self.block, self.kernel.args(
-                self.attn, self.qb, kb, vb, self.ob))
+            fill(self.ins, self.event_in, i % self.n_drawn)
+            self.fn.launch(self.grid, self.block,
+                           self.args(u, self.ins, self.outs))
         with span("copy_to_host"):
-            return self.ob.copy_to_host()
+            return to_host(self.outs)
 
     def warm(self) -> None:
         self._one(0, 0)
@@ -175,15 +210,15 @@ class LaunchPath(Path):
         t0 = time.perf_counter()
         i = 0
         while time.perf_counter() - t0 < seconds:
-            s = i % self.n_seq
+            u = i % self.n_units
             start = time.perf_counter()
-            o = self._one(i + 1, s)
+            outs = self._one(i + 1, u)
             end = time.perf_counter()
             self.bytes_in_use.append(device_bytes_in_use())
-            rec = {"kind": "launch", "seq": s, "q": (i + 1) % len(self.q),
+            rec = {"kind": "launch", "seq": u, "q": (i + 1) % self.n_drawn,
                    "start": start - t0, "end": end - t0, "ok": True}
             recs.append(rec)
-            self.answers.append((rec, self.shape_o(o)))
+            self.answers.append((rec, self.kernel.answer(self.shape, outs)))
             i += 1
         return recs, {"t0": 0.0, "t1": time.perf_counter() - t0}
 
@@ -197,8 +232,8 @@ class ServePath(Path):
         tenants = t["tenants"]
         # the arrival trace is part of the mix: drawn from the mix's own
         # schedule seed, the same for every run seed, so every seed offers
-        # the same work; the run seed draws the numbers and which of its
-        # tenant's sequences each request reads
+        # the same work; the run seed draws the inputs and which of its
+        # tenant's units each request reads
         sched = np.random.default_rng(int(t["schedule_seed"]))
         shape = 1.0 / (cv * cv)
         due, clock = [], 0.0
@@ -210,9 +245,9 @@ class ServePath(Path):
         pop = np.array([float(x["popularity"]) for x in tenants])
         who = sched.choice(len(tenants), size=len(due), p=pop / pop.sum())
         per = int(t["sequences_per_tenant"])
-        if per * len(tenants) != self.n_seq:
-            raise ValueError(f"{len(tenants)} tenants x {per} sequences "
-                             f"!= {self.n_seq} resident sequences")
+        if per * len(tenants) != self.n_units:
+            raise ValueError(f"{len(tenants)} tenants x {per} units "
+                             f"!= {self.n_units} resident units")
         pick = self.rng.integers(0, per, size=len(due))
         pool = int(t["queries"])
         self.events = [{"due": d, "tenant": int(w), "seq": int(w) * per
@@ -220,39 +255,37 @@ class ServePath(Path):
                        for i, (d, w, p) in enumerate(zip(due, who, pick))]
         return pool
 
-    def prepare(self, k, v, q) -> None:
+    def prepare(self, inputs: Dict) -> None:
         from repro.core import ServingFrontEnd
 
         t = self.traffic
         sess = self.session(BACKEND)
-        self.fn = sess.load(self.kernel.program(self.attn)).function()
-        self.resident(sess, k, v)
+        self.fn = sess.load(self.kernel.program(self.shape)).function()
+        self.resident(sess, inputs)
         self.front = ServingFrontEnd(sess, max_inflight=int(t["max_inflight"]))
         self.names = []
         for x in t["tenants"]:
             self.front.tenant(x["name"], weight=float(x["weight"]),
                               max_inflight=int(t["quota_per_tenant"]))
             self.names.append(x["name"])
-        d_out = self.grid * int(self.attn["head_dim"])
-        self.qbufs = [sess.alloc(q[e["q"]].size).copy_from_host(q[e["q"]])
-                      for e in self.events]
-        self.obufs = [sess.alloc(d_out) for _ in self.events]
-        self.warm_bufs = (sess.alloc(q[0].size).copy_from_host(q[0]),
-                          sess.alloc(d_out))
+        event = inputs["event"]
+        self.in_bufs = [fill(self.event_buffers(sess, event), event, e["q"])
+                        for e in self.events]
+        self.out_bufs = [self.output_buffers(sess) for _ in self.events]
+        self.warm_bufs = (fill(self.event_buffers(sess, event), event, 0),
+                          self.output_buffers(sess))
         self.inflight: List[int] = []
 
-    def _submit(self, tenant: str, qb, ob, s: int):
-        kb, vb = self.kv[s]
+    def _submit(self, tenant: str, ins: Dict, outs: Dict, u: int):
         with span("submit"):
             return self.front.submit(tenant, self.fn, self.grid, self.block,
-                                     self.kernel.args(self.attn, qb, kb, vb,
-                                                      ob))
+                                     self.args(u, ins, outs))
 
     def warm(self) -> None:
         self._submit(self.names[0], *self.warm_bufs, 0)
         with span("pump"):
             self.front.drain()
-        self.warm_bufs[1].copy_to_host()
+        to_host(self.warm_bufs[1])
 
     def window(self, seconds: float):
         from repro.core.serving import QuotaExceeded
@@ -272,8 +305,8 @@ class ServePath(Path):
                 e, rec = self.events[i], recs[i]
                 self.inflight.append(self.front.total_inflight)
                 try:
-                    ticket = self._submit(rec["tenant"], self.qbufs[i],
-                                          self.obufs[i], e["seq"])
+                    ticket = self._submit(rec["tenant"], self.in_bufs[i],
+                                          self.out_bufs[i], e["seq"])
                     open_.append((ticket, i))
                 except QuotaExceeded:
                     rec["refused"] = True
@@ -299,51 +332,54 @@ class ServePath(Path):
                     recs[j]["cancelled"] = True      # it will never come
                 elif ticket.record.finished:
                     with span("copy_to_host"):
-                        o = self.obufs[j].copy_to_host()
+                        outs = to_host(self.out_bufs[j])
                     end = time.perf_counter() - t0
                     rec = recs[j]
                     rec.update(end=end, ok=True,
                                latency_ms=(end - rec["due"]) * 1e3)
                     self.bytes_in_use.append(device_bytes_in_use())
-                    self.answers.append((rec, self.shape_o(o)))
+                    self.answers.append(
+                        (rec, self.kernel.answer(self.shape, outs)))
                 else:
                     still.append((ticket, j))
             open_ = still
         return recs, {"t0": 0.0, "t1": time.perf_counter() - t0}
 
 
-class MigratePath(Path):
-    """Closed loop: start on the source backend, pause at a key-tile
-    barrier, migrate, finish on the target backend."""
+class MigratePath(LaunchPath):
+    """Closed loop: start on the source backend, pause at one of the
+    kernel's rest points, migrate, finish on the target backend."""
 
-    def prepare(self, k, v, q) -> None:
+    def plan(self, seconds: float) -> int:
+        rests = getattr(self.kernel, "rests", None)
+        rests = rests(self.shape) if rests is not None else []
+        if not rests:
+            raise ValueError(
+                f"kernel {self.kernel_name!r} has no rest points: the "
+                f"migrate path pauses a launch at a barrier the kernel "
+                f"names in rests(shape)")
+        # every rest point once, in an order drawn from the seed, so every
+        # seed pauses at the same set
+        self.rests = self.rng.permutation(np.asarray(rests))
+        return super().plan(seconds)
+
+    def prepare(self, inputs: Dict) -> None:
         t = self.traffic
-        self.q = q
-        prog = self.kernel.program(self.attn)
+        prog = self.kernel.program(self.shape)
         self.src = self.session(t["source_backend"])
         self.dst = self.session(t["target_backend"])
         self.fn_src = self.src.load(prog).function()
         self.fn_dst = self.dst.load(prog).function()
-        self.resident(self.src, k, v)
-        self.qb = self.src.alloc(q[0].size)
-        self.ob = self.src.alloc(self.grid * int(self.attn["head_dim"]))
-        # the pause barriers: every inner tile barrier once, in an order
-        # drawn from the seed, so every seed pauses at the same set
-        nt = self.kernel.ntiles(self.attn)
-        self.tiles = self.rng.permutation(np.arange(1, nt))
-        self.migrations: List[Dict[str, float]] = []
+        self.bind(self.src, inputs)
 
-    def _one(self, i: int, s: int, tile: int):
+    def _one(self, i: int, u: int, rest: int):
         from repro.core import migrate
 
-        kb, vb = self.kv[s]
         with span("launch"):
-            self.qb.copy_from_host(self.q[i % len(self.q)])
+            fill(self.ins, self.event_in, i % self.n_drawn)
             rec = self.fn_src.launch_async(self.grid, self.block,
-                                           self.kernel.args(self.attn,
-                                                            self.qb, kb, vb,
-                                                            self.ob))
-            if rec.advance(max_segments=self.kernel.after_tile(tile)):
+                                           self.args(u, self.ins, self.outs))
+            if rec.advance(max_segments=rest):
                 raise RuntimeError("launch finished before its pause")
             block_on(rec.engine)
         paused = time.perf_counter()
@@ -356,34 +392,35 @@ class MigratePath(Path):
         with span("finish"):
             new.wait()
         with span("copy_to_host"):
-            o = new.buffer("O").copy_to_host()
+            outs = {name: new.buffer(name).copy_to_host()
+                    for name in self.outputs}
         # the target's buffers were made by the restore: release them, so
         # every migration lands on fresh buffers as on a fresh device
         for db in new.bindings.values():
             db.free()
         lm = dict(self.dst.stats["last_migration"])
         lm["downtime_ms"] = (first - paused) * 1e3
-        return o, lm, paused, first
+        return outs, lm, paused, first
 
     def warm(self) -> None:
-        self._one(0, 0, int(self.tiles[0]))
+        self._one(0, 0, int(self.rests[0]))
 
     def window(self, seconds: float):
         recs = []
         t0 = time.perf_counter()
         i = 0
         while time.perf_counter() - t0 < seconds:
-            s, tile = i % self.n_seq, int(self.tiles[i % len(self.tiles)])
+            u, rest = i % self.n_units, int(self.rests[i % len(self.rests)])
             start = time.perf_counter()
-            o, lm, paused, first = self._one(i + 1, s, tile)
+            outs, lm, paused, first = self._one(i + 1, u, rest)
             end = time.perf_counter()
             self.bytes_in_use.append(device_bytes_in_use())
-            rec = {"kind": "migration", "seq": s, "q": (i + 1) % len(self.q),
-                   "tile": tile, "start": start - t0, "end": end - t0,
+            rec = {"kind": "migration", "seq": u, "q": (i + 1) % self.n_drawn,
+                   "rest": rest, "start": start - t0, "end": end - t0,
                    "paused": paused - t0, "first": first - t0, "ok": True,
                    **lm}
             recs.append(rec)
-            self.answers.append((rec, self.shape_o(o)))
+            self.answers.append((rec, self.kernel.answer(self.shape, outs)))
             i += 1
         return recs, {"t0": 0.0, "t1": time.perf_counter() - t0}
 
@@ -392,11 +429,22 @@ PATHS = {"launch": LaunchPath, "serve": ServePath, "migrate": MigratePath}
 
 
 def path_for(config: Dict, traffic: Dict, kernel, seed: int) -> Path:
+    """The mix's path: one of :data:`PATHS`, or the ``PATH`` class of
+    ``paths/<path>.py`` beside this module."""
     kind = traffic.get("path")
-    if kind not in PATHS:
-        raise ValueError(f"traffic path {kind!r} is not one of "
-                         f"{sorted(PATHS)}")
-    return PATHS[kind](config, traffic, kernel, seed)
+    if kind in PATHS:
+        cls = PATHS[kind]
+    else:
+        from harness import BENCH, load_module
+
+        try:
+            cls = load_module(BENCH / "paths" / f"{kind}.py",
+                              f"bench_path_{kind}").PATH
+        except FileNotFoundError:
+            raise ValueError(f"traffic path {kind!r} is neither one of "
+                             f"{sorted(PATHS)} nor a file paths/{kind}.py"
+                             ) from None
+    return cls(config, traffic, kernel, seed)
 
 
 def percentile(values: List[float], q: float) -> Optional[float]:

@@ -9,12 +9,19 @@ program holds.
 
 Shapes: ``q`` is ``(H, D)``, ``k`` and ``v`` are ``(H, S, D)``; every query
 head reads its own key/value rows (no grouped KV heads).
+
+:func:`draw`, :func:`check` and :func:`work` are the reference interface
+that ``bench/harness.py`` describes; ``attn`` is the configuration's
+shape block.
 """
 from __future__ import annotations
 
 from functools import lru_cache
+from typing import Dict, List
 
 import numpy as np
+
+import work as work_counts
 
 # sub-streams of the run's key: keys, values, queries
 _K, _V, _Q = 1, 2, 3
@@ -61,6 +68,14 @@ def inputs(seed: int, H: int, S: int, D: int, n_seq: int, n_q: int):
         k[s] = draw(key, _K, s)
         v[s] = draw(key, _V, s)
     return k, v, np.stack(queries(seed, range(n_q), H, D))
+
+
+def draw(seed: int, attn: Dict, n_units: int, n_events: int) -> Dict:
+    """The run's inputs by role: each resident sequence's ``K`` and ``V``
+    and each event's ``Q``, as :func:`inputs` draws them."""
+    k, v, q = inputs(seed, int(attn["heads"]), int(attn["keys"]),
+                     int(attn["head_dim"]), n_units, n_events)
+    return {"unit": {"K": k, "V": v}, "shared": {}, "event": {"Q": q}}
 
 
 def sequence(seed: int, s: int, H: int, S: int, D: int):
@@ -149,3 +164,35 @@ def rel_err(o, ref) -> float:
     if not np.all(np.isfinite(gap)):
         return float("inf")
     return float(gap.max() / max(np.abs(ref).max(), 1e-30))
+
+
+def check(answers, attn: Dict, seed: int, control: bool = False
+          ) -> Dict[str, object]:
+    """Each answer's O against the reference's, grouped by resident
+    sequence (``seq``) so each sequence's keys and values are drawn once;
+    ``q`` names the answer's query.  With ``control`` the reference's
+    lower-precision twin is compared in the program's place, over the
+    same queries."""
+    H, D = int(attn["heads"]), int(attn["head_dim"])
+    S, scale = int(attn["keys"]), float(attn["scale"])
+    by_seq: Dict[int, List] = {}
+    for rec, o in answers:
+        by_seq.setdefault(rec["seq"], []).append((rec, o))
+    worst, n = 0.0, 0
+    for s, items in sorted(by_seq.items()):
+        k, v = (np.asarray(x, np.float64) for x in sequence(seed, s, H, S, D))
+        qs = queries(seed, [rec["q"] for rec, _ in items], H, D)
+        if control:
+            kc, vc = split_high(k), split_high(v)
+        for (rec, o), q in zip(items, qs):
+            want = decode(q, k, v, scale)
+            got = decode_control(q, kc, vc, scale) if control else o
+            worst = max(worst, rel_err(got, want))
+            n += 1
+    return {"o_err_max": worst, "compared": n}
+
+
+def work(attn: Dict) -> Dict[str, int]:
+    """The algorithm's work for one launch: ``work.attn_decode``."""
+    return work_counts.attn_decode(int(attn["heads"]), int(attn["keys"]),
+                                   int(attn["head_dim"]))

@@ -38,21 +38,16 @@ def main(argv=None) -> int:
     device = harness.require_accelerator(int(workload["chips"]))
     config = harness.load_json("configs", workload["config"])
     traffic = harness.load_json("traffic", workload["traffic"])
-    kernel = harness.load_module(harness.BENCH / "kernels" /
-                                 f"{config['kernel']}.py", "sweep_kernel")
-    ref = harness.load_module(harness.BENCH / "reference" /
-                              f"{config['kernel']}.py", "sweep_ref")
-    attn = config["attn"]
+    kernel, ref = harness.kernel_modules(config)
+    shape = kernel.shape(config)
     print(f"device: {device}", flush=True)
     for rate in (float(r) for r in opts.rates.split(",")):
         path = loadgen.path_for(config, dict(traffic, rate_per_s=rate),
                                 kernel, opts.seed)
-        n_q = path.plan(opts.seconds)
-        k, v, q = ref.inputs(opts.seed, int(attn["heads"]),
-                             int(attn["keys"]), int(attn["head_dim"]),
-                             path.n_seq, n_q)
-        path.prepare(k, v, q)
-        del k, v, q
+        n_events = path.plan(opts.seconds)
+        inputs = ref.draw(opts.seed, shape, path.n_units, n_events)
+        path.prepare(inputs)
+        del inputs
         path.warm()
         recs, window = path.window(opts.seconds)
         path.release()

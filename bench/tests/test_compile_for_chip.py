@@ -62,7 +62,8 @@ def _signatures(config):
                     (B, T), ir.np_dtype(r.dtype)))
 
     z = lambda n: np.zeros(n, np.float32)
-    args = kernel.args(attn, z(H * D), z(H * S * D), z(H * S * D), z(H * D))
+    args = kernel.args(attn, {"Q": z(H * D), "K": z(H * S * D),
+                              "V": z(H * S * D), "O": z(H * D)})
     rec = Signatures()
     grid, block = kernel.geometry(attn)
     assert Engine(kernel.program(attn), rec, grid, block, args).run()

@@ -28,6 +28,18 @@ def test_every_name_resolves_to_its_file(bench_spec):
         assert harness.metric_reader(m["name"]).is_file()
 
 
+def test_every_traffic_path_resolves(bench_spec):
+    """A mix's path is one of ``loadgen.PATHS`` or a file
+    ``paths/<path>.py``."""
+    import loadgen
+
+    for w in bench_spec["workloads"]:
+        path = json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
+                          .read_text())["path"]
+        assert path in loadgen.PATHS or \
+            (BENCH / "paths" / f"{path}.py").is_file()
+
+
 def test_every_cell_reports_what_it_must(bench_spec):
     import end_to_end
     import run

@@ -27,6 +27,25 @@ def test_cell_bounds_are_memory_bound(name, bytes_, bound_us):
     assert work.bound_s(w, peak) * 1e6 == pytest.approx(bound_us, abs=0.01)
 
 
+@pytest.mark.parametrize("name,bytes_", [
+    ("glm4-9b-attn", 268_468_224),
+    ("granite-3b-a800m-attn", 25_178_112),
+])
+def test_harness_finds_the_count_through_the_kernels_reference(name,
+                                                               bytes_):
+    """The count ``run_cell`` bounds a launch by: the configuration's
+    reference module's ``work`` of the kernel module's shape block."""
+    import harness
+
+    config = harness.load_json("configs", name)
+    kernel, ref = harness.kernel_modules(config)
+    attn = kernel.shape(config)
+    w = ref.work(attn)
+    assert w == work.attn_decode(attn["heads"], attn["keys"],
+                                 attn["head_dim"])
+    assert w["bytes"] == bytes_
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(KeyError):
         work.peaks("cpu")
